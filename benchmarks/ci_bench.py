@@ -340,38 +340,34 @@ def perf_payload(fast: bool = True):
         pack_rows[key] = {"us_per_call": row["us_per_call"],
                           "derived": row["derived"]}
 
-    kernel_hlo = {}
-    try:
-        import functools
+    import functools
 
-        import jax.numpy as jnp
-        from jax import export as jexport
+    import jax.numpy as jnp
+    from jax import export as jexport
 
-        from repro.kernels.pack import (pack_update_pallas,
-                                        qsgd_pack_update_pallas,
-                                        randk_update_pallas)
+    from repro.kernels.pack import (pack_update_pallas,
+                                    qsgd_pack_update_pallas,
+                                    randk_update_pallas)
 
-        sds = jax.ShapeDtypeStruct((64, 256), jnp.float32)
-        idx = jax.ShapeDtypeStruct((32,), jnp.int32)
-        norm = jax.ShapeDtypeStruct((1, 1), jnp.float32)
-        exports = {
-            "block_topk_pack": jexport.export(
-                jax.jit(functools.partial(pack_update_pallas, lam=0.9, kb=16,
-                                          interpret=False)),
-                platforms=["tpu"])(sds, sds),
-            "randk_update": jexport.export(
-                jax.jit(functools.partial(randk_update_pallas, scale=75.0,
-                                          lam=0.9, interpret=False)),
-                platforms=["tpu"])(sds, sds, idx),
-            "qsgd_pack": jexport.export(
-                jax.jit(functools.partial(qsgd_pack_update_pallas, s=16,
-                                          lam=0.9, interpret=False)),
-                platforms=["tpu"])(sds, sds, sds, norm),
-        }
-        kernel_hlo = {k: len(e.mlir_module().encode())
-                      for k, e in exports.items()}
-    except Exception as e:  # jax.export unavailable on some versions
-        kernel_hlo = {"skipped": type(e).__name__}
+    sds = jax.ShapeDtypeStruct((64, 256), jnp.float32)
+    idx = jax.ShapeDtypeStruct((32,), jnp.int32)
+    norm = jax.ShapeDtypeStruct((1, 1), jnp.float32)
+    exports = {
+        "block_topk_pack": jexport.export(
+            jax.jit(functools.partial(pack_update_pallas, lam=0.9, kb=16,
+                                      interpret=False)),
+            platforms=["tpu"])(sds, sds),
+        "randk_update": jexport.export(
+            jax.jit(functools.partial(randk_update_pallas, scale=75.0,
+                                      lam=0.9, interpret=False)),
+            platforms=["tpu"])(sds, sds, idx),
+        "qsgd_pack": jexport.export(
+            jax.jit(functools.partial(qsgd_pack_update_pallas, s=16,
+                                      lam=0.9, interpret=False)),
+            platforms=["tpu"])(sds, sds, sds, norm),
+    }
+    kernel_hlo = {k: len(e.mlir_module().encode())
+                  for k, e in exports.items()}
 
     return {
         "schema": 1,

@@ -21,18 +21,9 @@ the committed zoo specs in examples/specs/ need no config at all.
 import argparse
 import dataclasses
 import math
-import os
 import sys
 
 sys.path.insert(0, "src")
-
-# force enough XLA host devices for the mesh BEFORE jax initializes
-if "XLA_FLAGS" not in os.environ:
-    _mesh = "4x1"
-    if "--mesh" in sys.argv:
-        _mesh = sys.argv[sys.argv.index("--mesh") + 1]
-    _n = math.prod(int(x) for x in _mesh.split("x"))
-    os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={_n}"
 
 from repro.models.config import ModelConfig  # noqa: E402
 
@@ -56,7 +47,14 @@ def main():
 
     from repro.core import ExperimentSpec
     from repro.core.spec import mesh_worker_count
+    from repro.launch import runtime
     from repro.train.loop import FinetuneLoop, FinetuneSettings
+
+    dims = [int(x) for x in args.mesh.split("x")]
+    # before the first compile or device query: the compile cache, and on
+    # the CPU enough host devices for the mesh
+    runtime.compile_cache()
+    runtime.cpu_devices(math.prod(dims))
 
     cfg = lm100m()
     steps = args.steps or (300 if not args.tiny else 60)
@@ -64,7 +62,6 @@ def main():
         cfg = dataclasses.replace(cfg, n_layers=4, d_model=256, d_ff=1024,
                                   vocab=4096, name="lm8m")
 
-    dims = [int(x) for x in args.mesh.split("x")]
     spec = ExperimentSpec(
         compressor="block_topk:1024,64", mode="efbv",
         agg="sparse_allgather", backend="shard_map",
@@ -78,7 +75,7 @@ def main():
     loop = FinetuneLoop(
         spec,
         FinetuneSettings(global_batch=16, seq_len=256, lr=1e-3,
-                         log_every=10, ckpt_dir="/tmp/lm100m_ckpt",
+                         log_every=10, ckpt_dir="runs/lm100m_ckpt",
                          ckpt_every=100),
         config=cfg)
     summary = loop.run()

@@ -43,12 +43,8 @@ def enable() -> None:
     os.environ[_ENV] = "1"
     import jax
 
-    jax.config.update("jax_debug_nans", True)
-    try:  # interpret-at-the-source, where available (newer jax)
-        from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas import tpu as pltpu
 
-        ctx = getattr(pltpu, "force_tpu_interpret_mode", None)
-        if ctx is not None:
-            ctx().__enter__()  # process-lifetime scope, deliberately unexited
-    except Exception:
-        pass  # kernels/ops.py's _interpret_default() hook still covers us
+    jax.config.update("jax_debug_nans", True)
+    # interpret at the source: process-lifetime scope, deliberately unexited
+    pltpu.force_tpu_interpret_mode().__enter__()

@@ -1,106 +1,52 @@
-"""Version tolerance for the jax surface this repo uses.
+"""The few jax calls this repo makes with fixed arguments, in one place.
 
-The code targets the modern API (jax.shard_map with ``axis_names``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.lax.pcast``,
-``jax.sharding.get_abstract_mesh``); older jaxlibs (0.4.x) ship the same
-machinery under different names and defaults:
-
-  * shard_map lives in jax.experimental.shard_map and takes ``auto=`` (the
-    complement of ``axis_names``) plus ``check_rep`` instead of the VMA
-    type system;
-  * Mesh has no axis_types (everything is implicitly Auto under GSPMD);
-  * there is no pcast -- without VMA tracking the cotangent of a
-    replicated input inside shard_map is already per-shard, so the cast
-    is a no-op;
-  * there is no abstract-mesh context, so the MoE sharding-constraint
-    hints simply don't apply (they are perf hints, not semantics).
-
-Every call site goes through this module so the rest of the codebase can be
-written against one API.
+Every mesh is GSPMD-auto on all axes, every shard_map is manual over the
+worker axes only, and the MoE sharding hints read the ambient abstract
+mesh; these helpers spell those conventions once.
 """
 
 from __future__ import annotations
 
-from typing import Any, Iterable, Optional, Sequence, Tuple
+from typing import Any, Iterable, Sequence, Tuple
 
 import jax
 
 PyTree = Any
 
-#: oldest jax release the shims below are exercised against; the CI tier-1
-#: matrix pins one leg to this (keep .github/workflows/ci.yml in sync) so a
-#: compat regression surfaces in PR CI, not at seed-repair time.
-OLDEST_SUPPORTED_JAX = "0.4.30"
-
-_HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-_HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_PCAST = hasattr(jax.lax, "pcast")
-
-# Old XLA's SPMD partitioner CHECK-fails on partial-auto shard_map when the
-# auto ('model') axis has size > 1; callers fall back to an equivalent vmap
-# formulation in that regime (see train/trainer.py).
-HAS_PARTIAL_AUTO_SHARD_MAP = _HAS_NEW_SHARD_MAP
-
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """jax.make_mesh with every axis GSPMD-auto, on any jax version."""
-    if _HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """jax.make_mesh with every axis GSPMD-auto."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, manual_axes: Iterable[str]):
     """shard_map manual over ``manual_axes``, GSPMD-auto over the rest."""
-    manual = set(manual_axes)
-    if _HAS_NEW_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=manual)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(a for a in mesh.axis_names if a not in manual)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False, auto=auto)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=set(manual_axes))
 
 
 def pcast_varying(tree: PyTree, axes: Tuple[str, ...]) -> PyTree:
-    """Mark a replicated value as varying over ``axes`` (VMA jaxes only).
-
-    On pre-VMA jax the distinction does not exist: differentiating w.r.t. a
-    replicated input inside shard_map already yields the per-shard cotangent,
-    so this is the identity.
-    """
-    if _HAS_PCAST:
-        return jax.lax.pcast(tree, tuple(axes), to="varying")
-    return tree
+    """Mark a replicated value as varying over ``axes``."""
+    return jax.lax.pcast(tree, tuple(axes), to="varying")
 
 
 def cost_analysis(compiled) -> dict:
-    """compiled.cost_analysis() as a flat dict on any jax version (old
-    jaxlibs return a one-element list of dicts)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """compiled.cost_analysis(), or {} where the backend reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def abstract_mesh():
-    """The ambient abstract mesh, or None when the API doesn't exist."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is None:
-        return None
-    return get()
+    """The ambient abstract mesh."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def auto_axes_of(mesh, *, exclude: Tuple[str, ...] = ()) -> Tuple[str, ...]:
-    """Names of GSPMD-auto axes of ``mesh`` minus ``exclude``; () if the
-    mesh carries no axis-type information (old jax: nothing is manual at the
-    GSPMD level, but we can't prove it, so constraints are skipped)."""
-    if mesh is None or getattr(mesh, "empty", True):
-        return ()
-    axis_types = getattr(mesh, "axis_types", None)
-    if axis_types is None or not _HAS_AXIS_TYPES:
+    """Names of GSPMD-auto axes of ``mesh`` minus ``exclude``; () outside
+    any mesh."""
+    if mesh is None or mesh.empty:
         return ()
     auto = jax.sharding.AxisType.Auto
-    return tuple(n for n, t in zip(mesh.axis_names, axis_types)
+    return tuple(n for n, t in zip(mesh.axis_names, mesh.axis_types)
                  if n not in exclude and t == auto)

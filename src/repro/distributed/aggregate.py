@@ -40,6 +40,7 @@ from typing import Any, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.efbv import EFBV, Downlink
 from repro.distributed import wire
@@ -168,11 +169,16 @@ def combine_global(
     mode: str = "dense_psum",
     wire_dtype: str = "float32",
     chunks: int = 1,
+    mesh=None,
 ) -> Tuple[PyTree, PyTree]:
     """d_bar = (1/n) sum_i d_i; g = h_avg + nu d_bar; h_avg <- h_avg + lam d_bar.
 
     ``message_stacked`` carries a leading worker axis of size n sharded over
-    (pod, data); the reduction over it IS the wire collective.
+    (pod, data); the reduction over it IS the wire collective.  With a
+    ``mesh``, the sparse_allgather payloads are gathered onto every device
+    before the local decode: left to itself the partitioner scatters each
+    worker's payload where it lies and all-reduces the DENSE result, so the
+    compressed exchange would travel uncompressed.
 
     ``chunks`` > 1 (the pipelined exchange) splits the worker axis of each
     sparse payload into that many equal slices and decode-sums them in fixed
@@ -188,6 +194,9 @@ def combine_global(
         fmt = wire.tree_format_for(algo.compressor, h_avg,
                                    wire_dtype=wire_dtype,
                                    rules=algo.leaf_rules)
+        if mesh is not None:
+            message_stacked = jax.lax.with_sharding_constraint(
+                message_stacked, NamedSharding(mesh, P()))
         d_bar_leaves = []
         for payload, codec, ref in zip(message_stacked, fmt.leaves,
                                        ref_leaves):

@@ -143,6 +143,13 @@ class LeafCodec:
         """True if a fused Pallas compress-and-pack kernel exists."""
         return False
 
+    @property
+    def kernel_gap(self) -> Optional[str]:
+        """Why this leaf cannot use its codec's fused kernel (it then takes
+        the jnp oracle, on a TPU too); None where the kernel runs or the
+        codec has none."""
+        return None
+
     # -- pack / unpack ------------------------------------------------------
     def encode(self, key: Optional[Array], delta: Array) -> Tuple[Array, ...]:
         """Flat f32 innovation -> payload tuple."""
@@ -238,10 +245,19 @@ class LeafWire(LeafCodec):
 
     @property
     def has_kernel(self) -> bool:
-        # non-f32 value payloads take the oracle: the control variate must
-        # track the DECODED payload (what the master adds), and the fused
-        # kernel updates h with the pre-cast f32 values
-        return self.block % 128 == 0 and self.val_dtype == "float32"
+        return self.kernel_gap is None
+
+    @property
+    def kernel_gap(self) -> Optional[str]:
+        # the Pallas kernel tiles 128-lane slabs.  Non-f32 value payloads
+        # take the oracle: the control variate must track the DECODED
+        # payload (what the master adds), and the fused kernel updates h
+        # with the pre-cast f32 values
+        if self.block % 128:
+            return f"block {self.block} is not a multiple of 128"
+        if self.val_dtype != "float32":
+            return f"{self.val_dtype} wire values"
+        return None
 
     def encode(self, key, delta):
         vals, idx = pack_oracle(self, delta)
@@ -314,10 +330,18 @@ class RandKSparse(FlatSparse):
 
     @property
     def has_kernel(self) -> bool:
+        return self.kernel_gap is None
+
+    @property
+    def kernel_gap(self) -> Optional[str]:
         # the kernel compares f32 linear positions (exact below 2**24) and
         # updates h with the unquantized f32 values (== the decoded payload
         # only for f32 wires)
-        return self.size < 2 ** 24 and self.val_dtype == "float32"
+        if self.size >= 2 ** 24:
+            return f"size {self.size} >= 2**24"
+        if self.val_dtype != "float32":
+            return f"{self.val_dtype} wire values"
+        return None
 
     def encode_update(self, key, g, h, lam, *, kernel=None, stream=False):
         del stream  # the rand-k gather kernel has no streaming variant
@@ -928,6 +952,15 @@ def tree_format_for(compressor, tree: PyTree, *, wire_dtype: str = "float32",
         return format_for(compressor, tree, wire_dtype=wire_dtype)
     return TreeWire.for_tree(compressor, tree, wire_dtype=wire_dtype,
                              rules=tuple(rules))
+
+
+def kernel_gaps(fmt: WireFormat, tree: PyTree) -> Tuple[Tuple[str, str], ...]:
+    """(leaf path, reason) for every leaf of ``tree`` whose codec has a
+    fused Pallas kernel that the leaf cannot use: under kernel 'auto' these
+    leaves take the jnp oracle on a TPU too."""
+    return tuple((path or "<root>", codec.kernel_gap)
+                 for path, codec in zip(leaf_paths(tree), fmt.leaves)
+                 if codec.kernel_gap is not None)
 
 
 def payload_bytes(payload: PyTree) -> int:

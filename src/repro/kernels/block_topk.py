@@ -28,9 +28,9 @@ TILE_NB = 8  # blocks (rows) per grid step
 def _select_mask(xa, kb: int):
     """(rows, block) magnitudes -> 0/1 keep-mask, kb per row, exact."""
     block = xa.shape[1]
-    # f32 column indices: Mosaic here lowers neither cumsum nor integer
-    # reductions; f32 is exact for block < 2**24
-    cols = jax.lax.broadcasted_iota(jnp.float32, xa.shape, 1)
+    # f32 column indices (exact for block < 2**24) from an int32 iota:
+    # Mosaic's tpu.iota is integer-only, and cumsum has no Mosaic lowering
+    cols = jax.lax.broadcasted_iota(jnp.int32, xa.shape, 1).astype(jnp.float32)
 
     def body(_, selected):
         score = jnp.where(selected > 0, -jnp.inf, xa)

@@ -12,7 +12,9 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro import compat
 from repro.kernels import block_topk as K
 from repro.kernels import pack as KP
 
@@ -26,6 +28,18 @@ def _interpret_default() -> bool:
     from repro.analysis import sanitize
 
     return sanitize.active() or jax.default_backend() != "tpu"
+
+
+def _unpartitioned(kernel, *operands):
+    """Call a Pallas kernel where its Mosaic lowering accepts it.  GSPMD
+    cannot partition a Mosaic kernel, so under a mesh with auto axes (the
+    trainers' 'model' axis, inside their shard_map over the worker axes) the
+    call runs in a shard_map over those axes, its operands replicated."""
+    auto = compat.auto_axes_of(compat.abstract_mesh())
+    if not auto:
+        return kernel(*operands)
+    return jax.shard_map(kernel, in_specs=P(), out_specs=P(),
+                         axis_names=set(auto))(*operands)
 
 
 def _to_slabs(x: Array, block: int, tile: int = K.TILE_NB
@@ -76,13 +90,14 @@ def efbv_pack_update(g: Array, h: Array, lam: float, block: int = 1024,
     DMAs toward HBM while the h update computes); bit-identical payloads.
     """
     interpret = _interpret_default() if interpret is None else interpret
-    gp, d_len, shape = _to_slabs(g, block)
+    tile = KP.STREAM_TILE_NB if stream else K.TILE_NB
+    gp, d_len, shape = _to_slabs(g, block, tile)
     # h keeps its own dtype: the kernel subtracts in f32, so pre-rounding h
     # to g.dtype would break bit-identity with the jnp oracle on mixed dtypes
-    hp, _, _ = _to_slabs(h, block)
-    vals, idx, h_out = KP.pack_update_pallas(gp, hp, lam, kb,
-                                             interpret=interpret,
-                                             stream=stream)
+    hp, _, _ = _to_slabs(h, block, tile)
+    vals, idx, h_out = _unpartitioned(
+        functools.partial(KP.pack_update_pallas, lam=lam, kb=kb,
+                          interpret=interpret, stream=stream), gp, hp)
     nb = -(-d_len // block)
     h_new = h_out.reshape(-1)[:d_len].reshape(shape)
     return (vals[:nb], idx[:nb]), h_new
@@ -103,8 +118,9 @@ def randk_update(g: Array, h: Array, idx: Array, lam: float, scale: float,
     interpret = _interpret_default() if interpret is None else interpret
     gp, d_len, _ = _to_slabs(g, _CODEC_COLS)
     hp, _, h_shape = _to_slabs(h, _CODEC_COLS)
-    h_out = KP.randk_update_pallas(gp, hp, idx, scale, lam,
-                                   interpret=interpret)
+    h_out = _unpartitioned(
+        functools.partial(KP.randk_update_pallas, scale=scale, lam=lam,
+                          interpret=interpret), gp, hp, idx)
     return h_out.reshape(-1)[:d_len].reshape(h_shape)
 
 
@@ -120,8 +136,9 @@ def qsgd_pack_update(g: Array, h: Array, u: Array, norm: Array, lam: float,
     gp, d_len, _ = _to_slabs(g, _CODEC_COLS, tile=KP.QS_TILE_NB)
     hp, _, h_shape = _to_slabs(h, _CODEC_COLS, tile=KP.QS_TILE_NB)
     up_, _, _ = _to_slabs(u, _CODEC_COLS, tile=KP.QS_TILE_NB)
-    lvl, h_out = KP.qsgd_pack_update_pallas(
-        gp, hp, up_, jnp.reshape(norm, (1, 1)).astype(jnp.float32), s, lam,
-        interpret=interpret)
+    lvl, h_out = _unpartitioned(
+        functools.partial(KP.qsgd_pack_update_pallas, s=s, lam=lam,
+                          interpret=interpret),
+        gp, hp, up_, jnp.reshape(norm, (1, 1)).astype(jnp.float32))
     levels = lvl.reshape(-1)[:d_len]
     return levels, h_out.reshape(-1)[:d_len].reshape(h_shape)

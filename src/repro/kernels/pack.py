@@ -39,40 +39,56 @@ from repro.kernels.block_topk import TILE_NB
 Array = jax.Array
 
 QS_TILE_NB = 32  # rows per grid step for int8 outputs (min int8 tile: 32x128)
+# rows per grid step of the streaming pack kernel: its payload is DMA'd
+# transposed, (kb, rows), and a DMA's minor dimension must fill 128 lanes
+STREAM_TILE_NB = 128
 
 
-def _select_block_topk(delta, kb: int):
-    """The shared selection core of both pack kernels: returns (vals f32
-    (rows, kb), cols f32 (rows, kb), selected bool (rows, block)).  One body
-    keeps the streaming and non-streaming variants bit-identical by
-    construction."""
+def _out(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """A kernel output varying over the mesh axes its operands vary over:
+    inside the trainers' shard_map every output must carry its vma."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+def _select_block_topk(delta, kb: int, axis: int = 1):
+    """The shared selection core of both pack kernels.  Blocks run along
+    ``axis`` of ``delta``; with axis=1 it returns (vals f32 (rows, kb), cols
+    f32 (rows, kb), selected bool (rows, block)), with axis=0 the transposed
+    (kb, rows) payloads of a (block, rows) input.  One body keeps the
+    streaming and non-streaming variants bit-identical by construction."""
     mag = jnp.abs(delta)
-    rows, block = mag.shape
-    # column indices kept in f32: Mosaic (this jaxlib vintage) implements
-    # neither integer reductions nor cumsum; f32 is exact for block < 2**24
-    cols = jax.lax.broadcasted_iota(jnp.float32, (rows, block), 1)
+    block = mag.shape[axis]
+    # column indices compared in f32 (exact for block < 2**24), the same
+    # compares the jnp oracle's tie-breaking is pinned against.  Mosaic's
+    # tpu.iota is integer-only, so the iota is built as int32 and converted;
+    # cumsum has no Mosaic lowering, hence the min-reduction tie-break below
+    cols = jax.lax.broadcasted_iota(jnp.int32, mag.shape, axis).astype(
+        jnp.float32)
 
     # python-unrolled over the (static, small) kb: payload columns are
     # assembled with one concatenate -- loop-carried dynamic_update_slice has
     # no Mosaic lowering, and the unroll keeps everything elementwise+reduce
-    selected = jnp.zeros((rows, block), jnp.bool_)
+    selected = jnp.zeros(mag.shape, jnp.bool_)
     v_cols, c_cols = [], []
     for _ in range(kb):
         score = jnp.where(selected, -jnp.inf, mag)
-        m = jnp.max(score, axis=1, keepdims=True)
+        m = jnp.max(score, axis=axis, keepdims=True)
         # m != -inf guards the all-selected row (kb == block); spelled as a
         # compare because isfinite has no Pallas TPU lowering
         is_m = (score == m) & (m != -jnp.inf)
         # exact first-index tie-breaking == jax.lax.top_k's stable order:
         # the smallest column index among the maxima
-        cmin = jnp.min(jnp.where(is_m, cols, float(block)), axis=1,
+        cmin = jnp.min(jnp.where(is_m, cols, float(block)), axis=axis,
                        keepdims=True)
         first = is_m & (cols == cmin)
-        v_cols.append(jnp.sum(jnp.where(first, delta, 0.0), axis=1)[:, None])
-        c_cols.append(jnp.max(jnp.where(first, cols, 0.0), axis=1)[:, None])
+        v_cols.append(jnp.sum(jnp.where(first, delta, 0.0), axis=axis,
+                              keepdims=True))
+        c_cols.append(jnp.max(jnp.where(first, cols, 0.0), axis=axis,
+                              keepdims=True))
         selected = selected | first
-    return (jnp.concatenate(v_cols, axis=1), jnp.concatenate(c_cols, axis=1),
-            selected)
+    return (jnp.concatenate(v_cols, axis=axis),
+            jnp.concatenate(c_cols, axis=axis), selected)
 
 
 def _pack_update_kernel(g_ref, h_ref, vals_ref, idx_ref, h_out_ref, *,
@@ -91,25 +107,31 @@ def _pack_update_kernel(g_ref, h_ref, vals_ref, idx_ref, h_out_ref, *,
 def _pack_update_stream_kernel(g_ref, h_ref, vals_ref, idx_ref, h_out_ref,
                                v_scr, i_scr, sems, *, kb: int, lam: float):
     """Async-copy variant: the payload slab is computed into VMEM scratch and
-    DMA'd toward its HBM output (vals_ref/idx_ref live in pltpu.ANY) while
+    DMA'd toward its HBM output (vals_ref/idx_ref live in pl.ANY) while
     the h update still computes -- the wire bytes of this grid step stream
     out under the remaining compute instead of waiting for the step's
-    epilogue.  Arithmetic is the non-streaming kernel's, op for op."""
+    epilogue.
+
+    Selection runs on the transposed (block, rows) tile, so the payload
+    comes out lane-dense as (kb, rows): Mosaic refuses a DMA whose minor
+    dimension (kb) is narrower than the 128-lane tiling.  Transposes and the
+    selection are exact, so the results are the non-streaming kernel's,
+    bit for bit."""
     t = pl.program_id(0)
     g = g_ref[...]
     h = h_ref[...]
-    delta = g.astype(jnp.float32) - h.astype(jnp.float32)
-    vals, cols, selected = _select_block_topk(delta, kb)
+    delta_t = (g.astype(jnp.float32) - h.astype(jnp.float32)).T
+    vals, cols, selected = _select_block_topk(delta_t, kb, axis=0)
     v_scr[...] = vals.astype(v_scr.dtype)
     i_scr[...] = cols.astype(jnp.int32)
-    rows = v_scr.shape[0]
+    rows = v_scr.shape[1]
     v_dma = pltpu.make_async_copy(
-        v_scr, vals_ref.at[pl.ds(t * rows, rows), :], sems.at[0])
+        v_scr, vals_ref.at[:, pl.ds(t * rows, rows)], sems.at[0])
     i_dma = pltpu.make_async_copy(
-        i_scr, idx_ref.at[pl.ds(t * rows, rows), :], sems.at[1])
+        i_scr, idx_ref.at[:, pl.ds(t * rows, rows)], sems.at[1])
     v_dma.start()
     i_dma.start()
-    d = jnp.where(selected, delta, 0.0)
+    d = jnp.where(selected, delta_t, 0.0).T
     h_out_ref[...] = (h.astype(jnp.float32) + lam * d).astype(h_out_ref.dtype)
     # the wait doubles as the write-after-read guard: the next grid step may
     # not overwrite the scratch slabs until this step's copies have landed
@@ -119,36 +141,41 @@ def _pack_update_stream_kernel(g_ref, h_ref, vals_ref, idx_ref, h_out_ref,
 
 def pack_update_pallas(g2d: Array, h2d: Array, lam: float, kb: int, *,
                        interpret: bool = False, stream: bool = False):
-    """g2d/h2d: (nb, block) with nb % TILE_NB == 0, block % 128 == 0.
+    """g2d/h2d: (nb, block) with block % 128 == 0 and nb % TILE_NB == 0
+    (nb % STREAM_TILE_NB == 0 with ``stream=True``).
 
     Returns (values (nb, kb), indices (nb, kb) int32, h_new (nb, block)).
     ``stream=True`` takes the async-copy kernel (payload DMA overlaps the h
     update); results are bit-identical to the non-streaming kernel.
     """
     nb, block = g2d.shape
-    assert nb % TILE_NB == 0 and block % 128 == 0, (nb, block)
+    tile = STREAM_TILE_NB if stream else TILE_NB
+    assert nb % tile == 0 and block % 128 == 0, (nb, block, tile)
     assert 0 < kb <= block, (kb, block)
-    grid = (nb // TILE_NB,)
-    slab = pl.BlockSpec((TILE_NB, block), lambda i: (i, 0))
-    payload = pl.BlockSpec((TILE_NB, kb), lambda i: (i, 0))
-    out_shape = (jax.ShapeDtypeStruct((nb, kb), g2d.dtype),
-                 jax.ShapeDtypeStruct((nb, kb), jnp.int32),
-                 jax.ShapeDtypeStruct((nb, block), h2d.dtype))
+    grid = (nb // tile,)
+    slab = pl.BlockSpec((tile, block), lambda i: (i, 0))
+    out_shape = (_out((nb, kb), g2d.dtype, g2d, h2d),
+                 _out((nb, kb), jnp.int32, g2d, h2d),
+                 _out((nb, block), h2d.dtype, g2d, h2d))
     if stream:
-        return pl.pallas_call(
+        vals_t, idx_t, h_new = pl.pallas_call(
             functools.partial(_pack_update_stream_kernel, kb=kb,
                               lam=float(lam)),
             grid=grid,
             in_specs=[slab, slab],
-            out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                       pl.BlockSpec(memory_space=pltpu.ANY),
+            out_specs=(pl.BlockSpec(memory_space=pl.ANY),
+                       pl.BlockSpec(memory_space=pl.ANY),
                        slab),
-            out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((TILE_NB, kb), g2d.dtype),
-                            pltpu.VMEM((TILE_NB, kb), jnp.int32),
+            out_shape=(_out((kb, nb), g2d.dtype, g2d, h2d),
+                       _out((kb, nb), jnp.int32, g2d, h2d),
+                       out_shape[2]),
+            scratch_shapes=[pltpu.VMEM((kb, tile), g2d.dtype),
+                            pltpu.VMEM((kb, tile), jnp.int32),
                             pltpu.SemaphoreType.DMA((2,))],
             interpret=interpret,
         )(g2d, h2d)
+        return vals_t.T, idx_t.T, h_new
+    payload = pl.BlockSpec((tile, kb), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_pack_update_kernel, kb=kb, lam=float(lam)),
         grid=grid,
@@ -178,8 +205,10 @@ def _randk_update_kernel(idx_ref, g_ref, h_ref, h_out_ref, *, k: int,
     h = h_ref[...]
     delta = g.astype(jnp.float32) - h.astype(jnp.float32)
     rows, cols = delta.shape
-    lin = (jax.lax.broadcasted_iota(jnp.float32, (rows, cols), 0) * cols
-           + jax.lax.broadcasted_iota(jnp.float32, (rows, cols), 1))
+    # int32 iotas (Mosaic's tpu.iota is integer-only), compared in f32
+    lin = (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) * cols
+           + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+           ).astype(jnp.float32)
     base = t * (rows * cols)
 
     def body(j, mask):
@@ -215,7 +244,7 @@ def randk_update_pallas(g2d: Array, h2d: Array, idx: Array, scale: float,
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), slab, slab],
         out_specs=slab,
-        out_shape=jax.ShapeDtypeStruct((nr, cols), h2d.dtype),
+        out_shape=_out((nr, cols), h2d.dtype, idx, g2d, h2d),
         interpret=interpret,
     )(idx, g2d, h2d)
 
@@ -268,7 +297,7 @@ def qsgd_pack_update_pallas(g2d: Array, h2d: Array, u2d: Array, norm: Array,
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), slab, slab, slab],
         out_specs=(slab, slab),
-        out_shape=(jax.ShapeDtypeStruct((nr, cols), lvl_dtype),
-                   jax.ShapeDtypeStruct((nr, cols), h2d.dtype)),
+        out_shape=(_out((nr, cols), lvl_dtype, norm, g2d, h2d, u2d),
+                   _out((nr, cols), h2d.dtype, norm, g2d, h2d, u2d)),
         interpret=interpret,
     )(norm, g2d, h2d, u2d)

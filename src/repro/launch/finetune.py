@@ -9,40 +9,13 @@ this driver is spec-file-ONLY: the experiment identity comes entirely from
 the committed :class:`repro.core.ExperimentSpec` JSON; the flags below are
 runtime knobs (:class:`repro.train.loop.FinetuneSettings`) that never enter
 the fingerprint.  ``--processes`` builds the mesh with the multi-host
-process-major layout (simulated on CPU fake host devices).  See
+process-major layout (simulated on CPU host devices).  See
 docs/finetuning.md.
 """
 
 from __future__ import annotations
 
-import json
 import math
-import os
-import sys
-
-# enough XLA host devices for the spec's mesh BEFORE jax initializes (the
-# same pre-import constraint as launch/train.py / launch/dryrun.py)
-
-
-def _mesh_from_argv(argv):
-    try:
-        for i, a in enumerate(argv):
-            if a == "--spec" or a.startswith("--spec="):
-                path = a.split("=", 1)[1] if "=" in a else argv[i + 1]
-                with open(path) as f:
-                    return json.load(f).get("mesh", "")
-    except (IndexError, OSError, ValueError):
-        pass  # malformed argv / unreadable spec: argparse or main() reports
-    return ""
-
-
-if "XLA_FLAGS" not in os.environ:
-    _shape = _mesh_from_argv(sys.argv)
-    if _shape:
-        _n = math.prod(int(x) for x in _shape.split("x"))
-        if _n > 1:
-            os.environ["XLA_FLAGS"] = \
-                f"--xla_force_host_platform_device_count={_n}"
 
 
 def parse_args(argv=None):
@@ -91,6 +64,7 @@ def main(argv=None):
         print("[finetune] sanitize mode: jax_debug_nans + Pallas interpret")
 
     from repro.core import ExperimentSpec, SpecError
+    from repro.launch import runtime
     from repro.train.loop import FinetuneLoop, FinetuneSettings
 
     settings = FinetuneSettings(
@@ -103,6 +77,10 @@ def main(argv=None):
     try:
         with open(args.spec) as f:
             spec = ExperimentSpec.from_json(f.read())
+        # before the first compile or device query: the compile cache, and on
+        # the CPU enough host devices for the spec's mesh
+        runtime.compile_cache()
+        runtime.cpu_devices(math.prod(spec.mesh_dims()))
         loop = FinetuneLoop(spec, settings)
     except (SpecError, ValueError, OSError) as e:
         raise SystemExit(f"[finetune] bad experiment spec: {e}")

@@ -44,6 +44,13 @@ def make_mesh(shape: Sequence[int], axes: Optional[Sequence[str]] = None):
                 f"mesh dims {defaults}, got shape {tuple(shape)} with "
                 f"{len(shape)} dims -- pass axes= explicitly")
         axes = defaults[-len(shape):]
+    import jax
+
+    need, devices = int(np.prod(shape)), jax.devices()
+    if need > len(devices):
+        raise ValueError(
+            f"mesh {'x'.join(map(str, shape))} needs {need} devices, but this "
+            f"process has {len(devices)} {devices[0].platform} device(s)")
     return compat.make_mesh(tuple(shape), tuple(axes))
 
 
@@ -123,11 +130,8 @@ def make_multihost_mesh(shape: Sequence[int],
                     f"{i // per_process} -- sort by (process_index, id) "
                     f"before building the mesh")
     dev_array = np.asarray(devices, dtype=object).reshape(shape)
-    try:
-        return Mesh(dev_array, axes,
-                    axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):  # old jax: no axis_types kwarg
-        return Mesh(dev_array, axes)
+    return Mesh(dev_array, axes,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def process_worker_slice(shape: Sequence[int], num_processes: int,

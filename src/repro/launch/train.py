@@ -21,38 +21,10 @@ refused.  See docs/api.md.
 from __future__ import annotations
 
 import argparse
-import json
+import dataclasses
 import math
-import os
-import sys
 import time
-
-# On CPU hosts, force enough XLA host devices for the requested mesh BEFORE
-# jax initializes (same constraint as launch/dryrun.py).  The mesh comes
-# from --mesh, or -- for spec-driven runs -- from the --spec file itself.
-
-
-def _mesh_from_argv(argv):
-    try:
-        if "--mesh" in argv:
-            return argv[argv.index("--mesh") + 1]
-        for i, a in enumerate(argv):
-            if a == "--spec" or a.startswith("--spec="):
-                path = a.split("=", 1)[1] if "=" in a else argv[i + 1]
-                with open(path) as f:
-                    return json.load(f).get("mesh", "")
-    except (IndexError, OSError, ValueError):
-        pass  # malformed argv / unreadable spec: argparse or main() reports
-    return ""
-
-
-if "XLA_FLAGS" not in os.environ:
-    _shape = _mesh_from_argv(sys.argv)
-    if _shape:
-        _n = math.prod(int(x) for x in _shape.split("x"))
-        if _n > 1:
-            os.environ["XLA_FLAGS"] = \
-                f"--xla_force_host_platform_device_count={_n}"
+from typing import Any, Callable, NamedTuple
 
 import jax
 import numpy as np
@@ -60,8 +32,10 @@ import numpy as np
 from repro.checkpoint import save_checkpoint
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.core import ExperimentSpec, SpecError, build
+from repro.core.spec import mesh_worker_count
 from repro.data import SyntheticLM, make_batch_shardings
-from repro.launch.mesh import make_mesh, num_workers
+from repro.launch import runtime
+from repro.launch.mesh import num_workers
 from repro.models import build_model
 from repro.optim import adamw, cosine, wsd
 
@@ -175,66 +149,68 @@ def spec_from_args(args, n: int) -> ExperimentSpec:
     )
 
 
-def main(argv=None):
-    args = parse_args(argv)
-    if args.sanitize:
-        from repro.analysis import sanitize
+class Job(NamedTuple):
+    """Everything a training run holds once it is set up: the spec and its
+    :class:`repro.core.Run`, the mesh, the model config, the placed
+    TrainState, the data stream and the jitted step."""
 
-        sanitize.enable()
-        print("[train] sanitize mode: jax_debug_nans + Pallas interpret")
-    try:
-        if args.spec:
-            with open(args.spec) as f:
-                spec = ExperimentSpec.from_json(f.read())
-            if args.smoke and not spec.smoke:
-                # --smoke changes the MODEL (reduced config), so it is part
-                # of the experiment identity: fold it into the spec --
-                # including the tuning dimension, which must come from the
-                # config the run actually uses -- before anything derives
-                # from or embeds the fingerprint
-                import dataclasses
-                spec = dataclasses.replace(
-                    spec, smoke=True,
-                    d=tuning_dim(get_smoke_config(spec.problem))
-                    if spec.problem in ARCHS else spec.d)
-            if args.pipeline != "off" and spec.pipeline != args.pipeline:
-                # like --smoke, the schedule is part of the experiment
-                # identity: fold the override in before the fingerprint is
-                # derived or embedded anywhere
-                import dataclasses
-                spec = dataclasses.replace(spec, pipeline=args.pipeline)
-            if args.leaf_codecs and spec.leaf_codecs != args.leaf_codecs:
-                # the per-leaf wire is part of the experiment identity too:
-                # fold the override in before the fingerprint is derived
-                import dataclasses
-                spec = dataclasses.replace(spec, leaf_codecs=args.leaf_codecs)
-            if spec.backend == "reference":
-                raise SpecError(
-                    "the train driver runs the distributed trainers; a "
-                    "backend='reference' spec runs via "
-                    "repro.core.build(spec).reference()")
-            if spec.problem not in ARCHS:
-                # valid spec (e.g. a logreg trainer run wired up in user
-                # code, like examples/distributed_logreg.py), but this
-                # driver only trains the LM arch zoo
-                raise SpecError(
-                    f"this driver trains model archs {sorted(ARCHS)}; "
-                    f"problem={spec.problem!r} specs supply their own "
-                    "loss via repro.core.build(spec).train_step(...)")
-        else:
-            mesh_probe = make_mesh([int(x) for x in args.mesh.split("x")])
-            spec = spec_from_args(args, num_workers(mesh_probe))
-        run = build(spec)
-    except (SpecError, ValueError, OSError) as e:
-        raise SystemExit(f"[train] bad experiment spec: {e}")
+    spec: ExperimentSpec
+    run: Any
+    mesh: Any
+    n: int
+    cfg: Any
+    key: jax.Array
+    state: Any
+    data: SyntheticLM
+    step_fn: Callable
 
-    mesh = run.make_mesh()
-    n = num_workers(mesh)
-    cfg = (get_smoke_config(spec.problem) if spec.smoke
-           else get_config(spec.problem))
-    model = build_model(cfg)
 
-    # WSD schedule for minicpm (its assigned training recipe), cosine otherwise
+def load_spec(args) -> ExperimentSpec:
+    """The run's ExperimentSpec: the ``--spec`` file with the identity-
+    changing flag overrides folded in, or the flag namespace itself."""
+    if not args.spec:
+        return spec_from_args(
+            args, mesh_worker_count([int(x) for x in args.mesh.split("x")]))
+    with open(args.spec) as f:
+        spec = ExperimentSpec.from_json(f.read())
+    if args.smoke and not spec.smoke:
+        # --smoke changes the MODEL (reduced config), so it is part
+        # of the experiment identity: fold it into the spec --
+        # including the tuning dimension, which must come from the
+        # config the run actually uses -- before anything derives
+        # from or embeds the fingerprint
+        spec = dataclasses.replace(
+            spec, smoke=True,
+            d=tuning_dim(get_smoke_config(spec.problem))
+            if spec.problem in ARCHS else spec.d)
+    if args.pipeline != "off" and spec.pipeline != args.pipeline:
+        # like --smoke, the schedule is part of the experiment
+        # identity: fold the override in before the fingerprint is
+        # derived or embedded anywhere
+        spec = dataclasses.replace(spec, pipeline=args.pipeline)
+    if args.leaf_codecs and spec.leaf_codecs != args.leaf_codecs:
+        # the per-leaf wire is part of the experiment identity too:
+        # fold the override in before the fingerprint is derived
+        spec = dataclasses.replace(spec, leaf_codecs=args.leaf_codecs)
+    if spec.backend == "reference":
+        raise SpecError(
+            "the train driver runs the distributed trainers; a "
+            "backend='reference' spec runs via "
+            "repro.core.build(spec).reference()")
+    if spec.problem not in ARCHS:
+        # valid spec (e.g. a logreg trainer run wired up in user
+        # code, like examples/distributed_logreg.py), but this
+        # driver only trains the LM arch zoo
+        raise SpecError(
+            f"this driver trains model archs {sorted(ARCHS)}; "
+            f"problem={spec.problem!r} specs supply their own "
+            "loss via repro.core.build(spec).train_step(...)")
+    return spec
+
+
+def make_optimizer(args, spec: ExperimentSpec):
+    """AdamW under the run's schedule: WSD for minicpm (its assigned
+    training recipe), cosine otherwise."""
     sched_kind = args.schedule
     if sched_kind == "auto":
         sched_kind = "wsd" if spec.problem.startswith("minicpm") else "cosine"
@@ -245,28 +221,17 @@ def main(argv=None):
     else:
         sched = cosine(args.lr, total_steps=spec.steps,
                        warmup_steps=max(spec.steps // 20, 1))
-    opt = adamw(sched, weight_decay=0.01)
+    return adamw(sched, weight_decay=0.01)
+
+
+def print_wire(spec: ExperimentSpec, run, params, n: int) -> None:
+    """Exact wire accounting for the codec payload (docs/wire_format.md),
+    and the leaves whose fused Pallas kernel gives way to the jnp oracle;
+    every compressor declares a codec, so this always prints."""
+    from repro.distributed import wire
 
     algo, downlink, participation = run.algo, run.downlink, run.participation
     federated = run.federated
-    print(f"[train] arch={cfg.name} family={cfg.family} params~{cfg.param_count():,} "
-          f"workers={n} algo={spec.mode} lam={algo.lam:.4g} nu={algo.nu:.4g} "
-          f"agg={spec.agg}"
-          + (f" pipeline={spec.pipeline}" if not run.pipeline.is_off else "")
-          + (f" participation={spec.participation}" if federated else "")
-          + (f" downlink={spec.downlink}" if downlink else "")
-          + (f" fleet={spec.compressor}" if algo.fleet is not None else "")
-          + (f" leaf_codecs={spec.leaf_codecs}" if spec.leaf_codecs else ""))
-    print(f"[train] spec fingerprint={spec.fingerprint()}"
-          + (f" (from {args.spec})" if args.spec else ""))
-
-    key = jax.random.key(spec.seed)
-    params = model.init(key)
-    state = run.init_state(params, opt, mesh)
-
-    # exact wire accounting for the codec payload (docs/wire_format.md);
-    # every compressor declares a codec, so this always prints
-    from repro.distributed import wire
     up_fmt = wire.tree_format_for(algo.compressor, params,
                                   wire_dtype=spec.wire_dtype,
                                   rules=algo.leaf_rules) \
@@ -278,6 +243,11 @@ def main(argv=None):
         print(f"[train] wire: codec={','.join(kinds)} {up} bits/round/worker "
               f"uplink ({up / 8 / 2**20:.2f} MiB, "
               f"{up / max(dense, 1):.4f}x dense fp32)")
+        gaps = wire.kernel_gaps(up_fmt, params)
+        if gaps:
+            print(f"[train] wire: {len(gaps)} leaves take the jnp oracle "
+                  "instead of their Pallas kernel on TPU: "
+                  + "; ".join(f"{p} ({why})" for p, why in gaps))
         if federated:
             exp_s = participation.fraction(n) * n
             fed = up_fmt.bits_per_round(n_workers=n, participants=exp_s)
@@ -310,8 +280,53 @@ def main(argv=None):
               f"{total:g} bits/round up+down "
               f"({total / max(dense_total, 1):.4f}x dense both ways)")
 
-    shardings = run.state_shardings(mesh, model.param_specs(), state)
-    state = jax.tree.map(lambda x, s: jax.device_put(x, s), state, shardings)
+
+def setup(args) -> Job:
+    """Spec, mesh, model, optimizer, placed TrainState, data and the jitted
+    step of a run: ``build(spec)`` -> ``run.init_state`` ->
+    ``run.state_shardings`` -> ``run.train_step``."""
+    try:
+        spec = load_spec(args)
+        # before the first compile or device query: the compile cache, and on
+        # the CPU enough host devices for the spec's mesh
+        runtime.compile_cache()
+        runtime.cpu_devices(math.prod(spec.mesh_dims()))
+        run = build(spec)
+    except (SpecError, ValueError, OSError) as e:
+        raise SystemExit(f"[train] bad experiment spec: {e}")
+    try:
+        mesh = run.make_mesh()
+    except ValueError as e:
+        raise SystemExit(f"[train] {e}")
+    n = num_workers(mesh)
+    cfg = (get_smoke_config(spec.problem) if spec.smoke
+           else get_config(spec.problem))
+    model = build_model(cfg)
+    opt = make_optimizer(args, spec)
+
+    algo = run.algo
+    print(f"[train] arch={cfg.name} family={cfg.family} params~{cfg.param_count():,} "
+          f"workers={n} algo={spec.mode} lam={algo.lam:.4g} nu={algo.nu:.4g} "
+          f"agg={spec.agg}"
+          + (f" pipeline={spec.pipeline}" if not run.pipeline.is_off else "")
+          + (f" participation={spec.participation}" if run.federated else "")
+          + (f" downlink={spec.downlink}" if run.downlink else "")
+          + (f" fleet={spec.compressor}" if algo.fleet is not None else "")
+          + (f" leaf_codecs={spec.leaf_codecs}" if spec.leaf_codecs else ""))
+    print(f"[train] spec fingerprint={spec.fingerprint()}"
+          + (f" (from {args.spec})" if args.spec else ""))
+
+    key = jax.random.key(spec.seed)
+    params = jax.eval_shape(model.init, key)
+    print_wire(spec, run, params, n)
+
+    # the state is built in place, each leaf straight into its sharding: no
+    # device ever holds an unsharded copy (h alone is n x the parameters)
+    shardings = run.state_shardings(
+        mesh, model.param_specs(),
+        jax.eval_shape(lambda p: run.init_state(p, opt, mesh), params))
+    state = jax.jit(lambda k: run.init_state(model.init(k), opt, mesh),
+                    out_shardings=shardings)(key)
 
     data = SyntheticLM(vocab=cfg.vocab, seq_len=args.seq,
                        global_batch=args.global_batch, n_workers=n,
@@ -323,21 +338,41 @@ def main(argv=None):
         return model.loss(p, batch)
 
     step_fn = run.train_step(loss_fn, opt, mesh)
+    return Job(spec, run, mesh, n, cfg, key, state, data, step_fn)
+
+
+def batch_at(job: Job, args, step: int) -> dict:
+    """Step ``step``'s global batch, placed on the mesh."""
+    cfg = job.cfg
+    batch = make_batch_shardings(job.mesh, job.data.batch(step))
+    if cfg.family == "vlm":
+        batch["vision_embeds"] = jax.device_put(
+            np.random.default_rng(step).standard_normal(
+                (args.global_batch, cfg.vision_patches, cfg.d_model),
+                dtype=np.float32))
+    if cfg.family == "encdec":
+        batch["frames"] = jax.device_put(
+            np.random.default_rng(step).standard_normal(
+                (args.global_batch, cfg.encoder_frames, cfg.d_model),
+                dtype=np.float32))
+    return batch
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    if args.sanitize:
+        from repro.analysis import sanitize
+
+        sanitize.enable()
+        print("[train] sanitize mode: jax_debug_nans + Pallas interpret")
+    job = setup(args)
+    spec, n, state = job.spec, job.n, job.state
 
     t_start = time.time()
     for step in range(spec.steps):
-        batch = make_batch_shardings(mesh, data.batch(step))
-        if cfg.family == "vlm":
-            batch["vision_embeds"] = jax.device_put(
-                np.random.default_rng(step).standard_normal(
-                    (args.global_batch, cfg.vision_patches, cfg.d_model),
-                    dtype=np.float32))
-        if cfg.family == "encdec":
-            batch["frames"] = jax.device_put(
-                np.random.default_rng(step).standard_normal(
-                    (args.global_batch, cfg.encoder_frames, cfg.d_model),
-                    dtype=np.float32))
-        state, metrics = step_fn(state, batch, jax.random.fold_in(key, step))
+        batch = batch_at(job, args, step)
+        state, metrics = job.step_fn(state, batch,
+                                     jax.random.fold_in(job.key, step))
         if step % args.log_every == 0 or step == spec.steps - 1:
             m = {k: float(v) for k, v in metrics.items()}
             part_str = f"|S|={int(m['participants'])}/{n} " \

@@ -53,7 +53,7 @@ from repro.distributed.aggregate import (broadcast_global, combine_global,
 from repro.distributed.spec import (
     batch_spec, linear_worker_index, stack_worker_spec, to_named_sharding,
 )
-from repro.launch.mesh import MODEL_AXIS, num_workers, worker_axes
+from repro.launch.mesh import num_workers, worker_axes
 from repro.optim.optimizers import Optimizer, apply_updates, global_norm
 
 PyTree = Any
@@ -231,8 +231,6 @@ def make_train_step(
         loss_fn = jax.checkpoint(loss_fn)
 
     # ---- phase 1: worker-local grad + compress (manual over worker axes) ----
-    # One body shared by both phase-1 formulations below, so the shard_map
-    # and vmap paths cannot drift apart.
     def worker_body(params_for_grad, h_i, batch_i, kw, m=None, widx=None,
                     stream=False):
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
@@ -263,54 +261,25 @@ def make_train_step(
         params_v = compat.pcast_varying(params, tuple(waxes))
         h_loc = jax.tree.map(lambda a: a[0], h)
         m = None if mask is None else mask[0]
-        # streaming (payload DMA under the h update) only on this un-vmapped
-        # path: pallas_call batching would re-purpose the grid dim the
-        # streaming kernel slices its HBM outputs by
+        # streaming (payload DMA under the h update) only here, un-vmapped:
+        # pallas_call batching would re-purpose the grid dim the streaming
+        # kernel slices its HBM outputs by
         message, h_loc_new, local_metrics = worker_body(
             params_v, h_loc, batch, kw, m, widx, stream=pipelined)
         # stack everything on the worker axis
         stack = lambda t: jax.tree.map(lambda a: a[None], t)
         return stack(message), stack(h_loc_new), stack(local_metrics)
 
-    # Old jaxlibs miscompile *partial*-auto shard_map (manual worker axes +
-    # auto 'model' axis with size > 1 trips an SPMD-partitioner CHECK).  The
-    # vmap formulation below is the same per-worker math under pure GSPMD --
-    # worker-major batch reshape, worker keys fold_in(key, i) identical to
-    # linear_worker_index -- so the two phase-1s are bit-equivalent for
-    # deterministic compressors and draw-equivalent for random ones.
-    model_size = mesh.shape.get(MODEL_AXIS, 1)
-    use_shard_map = compat.HAS_PARTIAL_AUTO_SHARD_MAP or model_size == 1
-
-    if use_shard_map:
-        base_in_specs = (P(), P(waxes), batch_spec(mesh), P())
-        local_sharded = compat.shard_map(
-            local_phase,
-            mesh=mesh,
-            # the (n,) participation mask rides in worker-sharded: inside the
-            # manual region each worker sees its own scalar mask bit
-            in_specs=base_in_specs + ((P(waxes),) if federated else ()),
-            out_specs=(P(waxes), P(waxes), P(waxes)),
-            manual_axes=waxes,
-        )
-    else:
-        def local_sharded(params, h, batch, key, mask=None):
-            wb = jax.tree.map(
-                lambda a: a.reshape((n, a.shape[0] // n) + a.shape[1:]), batch)
-            wb = jax.lax.with_sharding_constraint(
-                wb, jax.tree.map(lambda _: NamedSharding(mesh, P(waxes)), wb))
-
-            def one_worker(i, h_i, wbatch):
-                return worker_body(params, h_i, wbatch,
-                                   jax.random.fold_in(key, i), widx=i)
-
-            if mask is None:
-                return jax.vmap(one_worker)(jnp.arange(n), h, wb)
-
-            def one_worker_masked(i, h_i, wbatch, m):
-                return worker_body(params, h_i, wbatch,
-                                   jax.random.fold_in(key, i), m, i)
-
-            return jax.vmap(one_worker_masked)(jnp.arange(n), h, wb, mask)
+    base_in_specs = (P(), P(waxes), batch_spec(mesh), P())
+    local_sharded = compat.shard_map(
+        local_phase,
+        mesh=mesh,
+        # the (n,) participation mask rides in worker-sharded: inside the
+        # manual region each worker sees its own scalar mask bit
+        in_specs=base_in_specs + ((P(waxes),) if federated else ()),
+        out_specs=(P(waxes), P(waxes), P(waxes)),
+        manual_axes=waxes,
+    )
 
     # ---- full step: phase 1 + phase 2 under one jit ---------------------------
     def train_step(state: TrainState, batch, key):
@@ -335,7 +304,7 @@ def make_train_step(
         apply_msg = state.inflight if pipelined else message
         g, h_avg_new = combine_global(
             algo, apply_msg, state.h_avg, n_workers=n, mode=agg_mode,
-            wire_dtype=wire_dtype, chunks=chunks)
+            wire_dtype=wire_dtype, chunks=chunks, mesh=mesh)
 
         updates, opt_state = optimizer.update(g, state.opt_state, state.params)
         params = apply_updates(state.params, updates)
@@ -498,7 +467,8 @@ def make_train_step_fsdp(
         apply_msg = state.inflight if pipelined else message
         g, h_avg_new = combine_global(algo, apply_msg, state.h_avg,
                                       n_workers=n, mode=agg_mode,
-                                      wire_dtype=wire_dtype, chunks=chunks)
+                                      wire_dtype=wire_dtype, chunks=chunks,
+                                      mesh=mesh)
         updates, opt_state = optimizer.update(g, state.opt_state, state.params)
         params = apply_updates(state.params, updates)
         metrics = {"loss": jnp.mean(loss), "g_norm": global_norm(g),

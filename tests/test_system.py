@@ -1,9 +1,12 @@
 """End-to-end behaviour tests for the paper's system: the full train driver
 (EF-BV in the loop) and the serve driver, on reduced configs."""
 
+import importlib.util
+import os
+
 import pytest
 
-from conftest import run_with_devices
+from conftest import REPO, run_with_devices
 
 
 @pytest.mark.slow
@@ -102,3 +105,70 @@ def test_train_driver_spec_file_smoke(tmp_path):
         print("SPEC_SMOKE_OK", loss)
     """, n_devices=4, timeout=1200)
     assert "SPEC_SMOKE_OK" in out
+
+
+def _chip_smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("env, why", [
+    ({}, "no TPU"),
+    ({"REPRO_SANITIZE": "1"}, "REPRO_SANITIZE=1"),
+    ({"REPRO_WIRE_KERNEL": "oracle"}, "REPRO_WIRE_KERNEL='oracle'"),
+], ids=["no-tpu", "sanitize", "oracle-kernel"])
+def test_chip_smoke_refuses_off_the_device_path(monkeypatch, capsys, env,
+                                                why):
+    """chip_smoke.py exits nonzero, printing no result line, where JAX finds
+    no TPU or a switch would move the kernels off the device."""
+    from repro.launch import runtime
+
+    for k in ("REPRO_SANITIZE", "REPRO_WIRE_KERNEL"):
+        monkeypatch.delenv(k, raising=False)
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    monkeypatch.setattr(runtime, "compile_cache", lambda: "")
+    assert _chip_smoke().main([]) == 1
+    out, err = capsys.readouterr()
+    assert why in err
+    assert '"ok"' not in out
+
+
+@pytest.mark.parametrize("env_dir", [True, False], ids=["env", "checkout"])
+def test_compile_cache_placement(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; unset, the
+    cache sits at one fixed path inside the checkout."""
+    import jax
+
+    from repro.launch import runtime
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert runtime.compile_cache() == str(tmp_path)
+        assert updates == []
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(REPO, ".jax_cache")
+        assert runtime.CACHE_DIR == want
+        assert runtime.compile_cache() == want
+        assert updates == [("jax_compilation_cache_dir", want)]
+
+
+def test_train_driver_mesh_larger_than_devices(monkeypatch):
+    """A mesh wider than the process's devices (the default 2x2 on one
+    chip) is a clear exit that names the device count."""
+    from repro.launch import runtime, train
+
+    monkeypatch.setattr(runtime, "compile_cache", lambda: "")
+    monkeypatch.setattr(runtime, "cpu_devices", lambda n: None)
+    with pytest.raises(SystemExit,
+                       match=r"mesh 2x2 needs 4 devices, but this process "
+                             r"has 1 cpu device\(s\)"):
+        train.main(["--arch", "mamba2-130m", "--smoke", "--mesh", "2x2",
+                    "--steps", "1"])

@@ -1,0 +1,78 @@
+"""Ahead-of-time compiles of the wire's Pallas kernels for a TPU v5e chip.
+
+The TPU compiler is installed with jaxlib and compiles for a chip that is
+described, not attached, so these run on a CPU-only host.  They catch what
+interpret mode cannot: Mosaic refusing an op (a float iota, a DMA narrower
+than the 128-lane tiling), a kernel over its VMEM or SMEM budget.  Shapes
+are qwen2-0.5b's (configs/qwen2_0_5b.py): the stacked MLP leaf of
+24 x 896 x 4864 f32 elements, and for rand-k (whose kernel compares f32
+positions below 2**24) the stacked k/v projection of 24 x 896 x 128.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import pack
+
+MLP_LEAF = 24 * 896 * 4864
+KV_LEAF = 24 * 896 * 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e:2x2 host, with the persistent compile
+    cache off: an entry compiled for a described chip cannot be read back
+    without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+    compilation_cache.reset_cache()
+
+
+def _compiled_text(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+@pytest.mark.parametrize("stream", [False, True], ids=["plain", "stream"])
+def test_block_topk_pack_compiles(one_chip, stream):
+    nb = MLP_LEAF // 256
+    slab = jax.ShapeDtypeStruct((nb, 256), jnp.float32, sharding=one_chip)
+    fn = functools.partial(pack.pack_update_pallas, lam=0.9, kb=16,
+                           stream=stream)
+    assert "tpu_custom_call" in _compiled_text(fn, slab, slab)
+
+
+def test_qsgd_pack_compiles(one_chip):
+    nr = MLP_LEAF // 1024
+    slab = jax.ShapeDtypeStruct((nr, 1024), jnp.float32, sharding=one_chip)
+    norm = jax.ShapeDtypeStruct((1, 1), jnp.float32, sharding=one_chip)
+    fn = functools.partial(pack.qsgd_pack_update_pallas, s=16, lam=0.9)
+    assert "tpu_custom_call" in _compiled_text(fn, slab, slab, slab, norm)
+
+
+def test_randk_update_compiles(one_chip):
+    nr = KV_LEAF // 1024
+    k = KV_LEAF // 100
+    slab = jax.ShapeDtypeStruct((nr, 1024), jnp.float32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((k,), jnp.int32, sharding=one_chip)
+    fn = functools.partial(pack.randk_update_pallas, scale=KV_LEAF / k,
+                           lam=0.9)
+    assert "tpu_custom_call" in _compiled_text(fn, slab, slab, idx)

@@ -408,3 +408,21 @@ def test_bidirectional_compressed_downlink_tracks_model(trainer):
         losses.append(float(m["loss"]))
     assert losses[-1] < 0.5 * losses[0], losses
     assert float(m["w_err"]) < 1.0
+
+
+@pytest.mark.parametrize("comp, wire_dtype, leaf, reason", [
+    (BlockTopK(256, 16), "float32", (4, 256), None),
+    (BlockTopK(100, 4), "float32", (4, 256),
+     "block 100 is not a multiple of 128"),
+    (BlockTopK(256, 16), "bfloat16", (4, 256), "bfloat16 wire values"),
+    (RandK(1000), "float32", (2 ** 24,), "size 16777216 >= 2**24"),
+], ids=["fits", "block", "bf16", "randk-size"])
+def test_kernel_gaps_name_each_oracle_leaf(comp, wire_dtype, leaf, reason):
+    """Leaves whose codec has a Pallas kernel they cannot use are named with
+    the reason: under kernel 'auto' they take the jnp oracle on a TPU too."""
+    tree = {"a": jax.ShapeDtypeStruct(leaf, jnp.float32),
+            "b": {"c": jax.ShapeDtypeStruct(leaf, jnp.float32)}}
+    fmt = wire.tree_format_for(comp, tree, wire_dtype=wire_dtype)
+    gaps = wire.kernel_gaps(fmt, tree)
+    assert gaps == (() if reason is None else
+                    (("a", reason), ("b/c", reason)))
