@@ -186,27 +186,36 @@ def combine_global(
     transfer of late ones.  ``chunks=1`` is byte-identical to the historical
     single decode-sum; the dense path ignores chunking (one psum is one
     transfer).
+
+    Named scopes (see train/trainer.py): the gather, or the dense mean, is
+    ``efbv.exchange``; the decode-sum and the master update are
+    ``efbv.decode``.
     """
     ref_leaves, treedef = jax.tree.flatten(h_avg)
     if mode == "dense_psum":
-        d_bar = jax.tree.map(lambda d: jnp.mean(d, axis=0), message_stacked)
+        with jax.named_scope("efbv.exchange"):
+            d_bar = jax.tree.map(lambda d: jnp.mean(d, axis=0),
+                                 message_stacked)
     else:
         fmt = wire.tree_format_for(algo.compressor, h_avg,
                                    wire_dtype=wire_dtype,
                                    rules=algo.leaf_rules)
         if mesh is not None:
-            message_stacked = jax.lax.with_sharding_constraint(
-                message_stacked, NamedSharding(mesh, P()))
+            with jax.named_scope("efbv.exchange"):
+                message_stacked = jax.lax.with_sharding_constraint(
+                    message_stacked, NamedSharding(mesh, P()))
         d_bar_leaves = []
-        for payload, codec, ref in zip(message_stacked, fmt.leaves,
-                                       ref_leaves):
-            # payload components carry a leading worker axis; the gather of
-            # the payload is the wire, the decode-sum is local (one codec,
-            # one layout, one combine for every compressor).
-            dense = wire.chunked_decode_sum(codec, payload, chunks)
-            d_bar_leaves.append((dense / n_workers).reshape(ref.shape))
+        with jax.named_scope("efbv.decode"):
+            for payload, codec, ref in zip(message_stacked, fmt.leaves,
+                                           ref_leaves):
+                # payload components carry a leading worker axis; the gather
+                # of the payload is the wire, the decode-sum is local (one
+                # codec, one layout, one combine for every compressor).
+                dense = wire.chunked_decode_sum(codec, payload, chunks)
+                d_bar_leaves.append((dense / n_workers).reshape(ref.shape))
         d_bar = jax.tree.unflatten(treedef, d_bar_leaves)
-    g, h_avg_new = algo.master_update(h_avg, d_bar)
+    with jax.named_scope("efbv.decode"):
+        g, h_avg_new = algo.master_update(h_avg, d_bar)
     return g, h_avg_new
 
 
@@ -259,7 +268,8 @@ def broadcast_global(
     math lives in one place.  ``key`` must be the round's
     ``downlink_key(step_key)`` so all paths draw the same broadcast.
     """
-    return downlink.broadcast(key, params, w, wire_dtype=wire_dtype)
+    with jax.named_scope("efbv.downlink"):
+        return downlink.broadcast(key, params, w, wire_dtype=wire_dtype)
 
 
 # --------------------------------------------------------------------------

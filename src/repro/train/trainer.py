@@ -34,6 +34,15 @@ The declarative way to obtain a train step is
 :class:`repro.core.ExperimentSpec` selects this builder vs
 :func:`make_train_step_fsdp` from ``spec.backend`` and threads
 agg/wire_dtype/downlink/participation from its fields (docs/api.md).
+
+Both trainers name the step's layers with ``jax.named_scope``, so that a
+profile of the compiled step splits its device time by layer (each
+instruction's ``op_name`` carries the innermost scope): ``efbv.fwd_bwd``
+(value_and_grad, the f32 cast, ``grad_transform``), ``efbv.compress``
+(compress_local), ``efbv.optimizer`` (update + apply_updates) and
+``efbv.step_metrics`` (the per-step norms); aggregate.py adds
+``efbv.exchange``, ``efbv.decode`` and ``efbv.downlink``.  The scopes are
+metadata only: the compiled program is the same without them.
 """
 
 from __future__ import annotations
@@ -160,6 +169,13 @@ def _inflight_shardings(mesh, inflight: PyTree):
         lambda _: NamedSharding(mesh, P(tuple(waxes))), inflight)
 
 
+def _optimizer_step(optimizer: Optimizer, g: PyTree, state: TrainState):
+    """(params, updates, opt_state) after one optimizer step on g."""
+    with jax.named_scope("efbv.optimizer"):
+        updates, opt_state = optimizer.update(g, state.opt_state, state.params)
+        return apply_updates(state.params, updates), updates, opt_state
+
+
 def make_train_step(
     loss_fn: Callable[[PyTree, Any], Tuple[jax.Array, dict]],
     optimizer: Optimizer,
@@ -233,21 +249,24 @@ def make_train_step(
     # ---- phase 1: worker-local grad + compress (manual over worker axes) ----
     def worker_body(params_for_grad, h_i, batch_i, kw, m=None, widx=None,
                     stream=False):
-        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params_for_grad, batch_i)
-        grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-        if grad_transform is not None:
-            grads = grad_transform(grads)
-        message, h_i_new = compress_local(algo, kw, grads, h_i, mode=agg_mode,
-                                          wire_dtype=wire_dtype, mask=m,
-                                          worker=widx, stream=stream)
-        local_metrics = {
-            "loss": loss,
-            "grad_norm": global_norm(grads),
-            "h_residual": global_norm(
-                jax.tree.map(lambda a, b: a - b, grads, h_i_new)),
-            **aux,
-        }
+        with jax.named_scope("efbv.fwd_bwd"):
+            (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                params_for_grad, batch_i)
+            grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+            if grad_transform is not None:
+                grads = grad_transform(grads)
+        with jax.named_scope("efbv.compress"):
+            message, h_i_new = compress_local(
+                algo, kw, grads, h_i, mode=agg_mode, wire_dtype=wire_dtype,
+                mask=m, worker=widx, stream=stream)
+        with jax.named_scope("efbv.step_metrics"):
+            local_metrics = {
+                "loss": loss,
+                "grad_norm": global_norm(grads),
+                "h_residual": global_norm(
+                    jax.tree.map(lambda a, b: a - b, grads, h_i_new)),
+                **aux,
+            }
         return message, h_i_new, local_metrics
 
     def local_phase(params, h, batch, key, mask=None):
@@ -306,12 +325,12 @@ def make_train_step(
             algo, apply_msg, state.h_avg, n_workers=n, mode=agg_mode,
             wire_dtype=wire_dtype, chunks=chunks, mesh=mesh)
 
-        updates, opt_state = optimizer.update(g, state.opt_state, state.params)
-        params = apply_updates(state.params, updates)
+        params, updates, opt_state = _optimizer_step(optimizer, g, state)
 
         metrics = {k: jnp.mean(v, axis=0) for k, v in local_metrics.items()}
-        metrics["g_norm"] = global_norm(g)
-        metrics["update_norm"] = global_norm(updates)
+        with jax.named_scope("efbv.step_metrics"):
+            metrics["g_norm"] = global_norm(g)
+            metrics["update_norm"] = global_norm(updates)
         if federated:
             metrics["participants"] = jnp.sum(mask)
 
@@ -323,8 +342,9 @@ def make_train_step(
             # partial participation decode the identical payload).
             w, _ = broadcast_global(downlink, downlink_key(key), params, w,
                                     wire_dtype=wire_dtype)
-            metrics["w_err"] = global_norm(
-                jax.tree.map(lambda a, b: a - b, params, w))
+            with jax.named_scope("efbv.step_metrics"):
+                metrics["w_err"] = global_norm(
+                    jax.tree.map(lambda a, b: a - b, params, w))
 
         new_state = TrainState(
             params=params,
@@ -434,11 +454,12 @@ def make_train_step_fsdp(
         keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(n))
 
         def one(wbatch):
-            (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, wbatch)
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-            if grad_transform is not None:
-                grads = grad_transform(grads)
+            with jax.named_scope("efbv.fwd_bwd"):
+                (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                    params, wbatch)
+                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+                if grad_transform is not None:
+                    grads = grad_transform(grads)
             return loss, aux, grads
 
         loss, aux, grads = jax.vmap(one)(wb)
@@ -453,39 +474,42 @@ def make_train_step_fsdp(
         widx = jnp.arange(n)
         if federated:
             mask = participation.sample_mask(participation_key(key), n)
-            message, h_new = jax.vmap(
-                lambda k, g, h, m, i: compress_local(
-                    algo, k, g, h, mode=agg_mode, wire_dtype=wire_dtype,
-                    mask=m, worker=i)
-            )(keys, grads, state.h, mask, widx)
+            with jax.named_scope("efbv.compress"):
+                message, h_new = jax.vmap(
+                    lambda k, g, h, m, i: compress_local(
+                        algo, k, g, h, mode=agg_mode, wire_dtype=wire_dtype,
+                        mask=m, worker=i)
+                )(keys, grads, state.h, mask, widx)
         else:
-            message, h_new = jax.vmap(
-                lambda k, g, h, i: compress_local(
-                    algo, k, g, h, mode=agg_mode, wire_dtype=wire_dtype,
-                    worker=i)
-            )(keys, grads, state.h, widx)
+            with jax.named_scope("efbv.compress"):
+                message, h_new = jax.vmap(
+                    lambda k, g, h, i: compress_local(
+                        algo, k, g, h, mode=agg_mode, wire_dtype=wire_dtype,
+                        worker=i)
+                )(keys, grads, state.h, widx)
         apply_msg = state.inflight if pipelined else message
         g, h_avg_new = combine_global(algo, apply_msg, state.h_avg,
                                       n_workers=n, mode=agg_mode,
                                       wire_dtype=wire_dtype, chunks=chunks,
                                       mesh=mesh)
-        updates, opt_state = optimizer.update(g, state.opt_state, state.params)
-        params = apply_updates(state.params, updates)
-        metrics = {"loss": jnp.mean(loss), "g_norm": global_norm(g),
-                   "update_norm": global_norm(updates),
-                   "grad_norm": jnp.mean(jax.vmap(global_norm)(grads)),
-                   "h_residual": jnp.mean(jax.vmap(
-                       lambda gi, hi: global_norm(jax.tree.map(
-                           lambda a, b: a - b, gi, hi)))(grads, h_new)),
-                   **{k: jnp.mean(v) for k, v in aux.items()}}
+        params, updates, opt_state = _optimizer_step(optimizer, g, state)
+        with jax.named_scope("efbv.step_metrics"):
+            metrics = {"loss": jnp.mean(loss), "g_norm": global_norm(g),
+                       "update_norm": global_norm(updates),
+                       "grad_norm": jnp.mean(jax.vmap(global_norm)(grads)),
+                       "h_residual": jnp.mean(jax.vmap(
+                           lambda gi, hi: global_norm(jax.tree.map(
+                               lambda a, b: a - b, gi, hi)))(grads, h_new)),
+                       **{k: jnp.mean(v) for k, v in aux.items()}}
         if federated:
             metrics["participants"] = jnp.sum(mask)
         w = state.w
         if downlink is not None:
             w, _ = broadcast_global(downlink, downlink_key(key), params, w,
                                     wire_dtype=wire_dtype)
-            metrics["w_err"] = global_norm(
-                jax.tree.map(lambda a, b: a - b, params, w))
+            with jax.named_scope("efbv.step_metrics"):
+                metrics["w_err"] = global_norm(
+                    jax.tree.map(lambda a, b: a - b, params, w))
         new_state = TrainState(params=params, opt_state=opt_state, h=h_new,
                                h_avg=h_avg_new, step=state.step + 1, w=w,
                                inflight=message if pipelined
