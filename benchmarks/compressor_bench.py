@@ -126,7 +126,9 @@ def fused_pack_hlo_report(nb: int = 64, block: int = 256, kb: int = 16):
         jexport.export(unfused, platforms=["tpu"])(sds).mlir_module())
 
     dense_ty = f"tensor<{nb}x{block}xf32>"
-    payload_tys = {f"tensor<{nb}x{kb}xf32>", f"tensor<{nb}x{kb}xi32>"}
+    # the kernel writes its payload lane-dense, (kb, nb); the (nb, kb)
+    # layout of the wire is a transpose outside the custom call
+    payload_tys = {f"tensor<{kb}x{nb}xf32>", f"tensor<{kb}x{nb}xi32>"}
     report = {
         # exactly one dense output (h_out) and the packed payload: d is
         # never materialized in HBM

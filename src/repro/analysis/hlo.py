@@ -685,7 +685,9 @@ def _block_topk_case():
     import jax.numpy as jnp
     from repro.kernels import pack
 
-    nb, block, kb = 32, 128, 4
+    # 384 blocks: pack_tile takes 256 rows a step, so the grid has two
+    # steps (the second ragged) and no step holds the whole leaf
+    nb, block, kb = 384, 128, 4
     g = jnp.zeros((nb, block), jnp.float32)
     h = jnp.zeros((nb, block), jnp.float32)
     fn = lambda g, h: pack.pack_update_pallas(g, h, 0.5, kb)

@@ -249,12 +249,17 @@ class LeafWire(LeafCodec):
 
     @property
     def kernel_gap(self) -> Optional[str]:
-        # the Pallas kernel tiles 128-lane slabs.  Non-f32 value payloads
+        # the Pallas kernel tiles 128-lane slabs, at least 128 blocks a grid
+        # step, and that tile must fit its VMEM.  Non-f32 value payloads
         # take the oracle: the control variate must track the DECODED
         # payload (what the master adds), and the fused kernel updates h
         # with the pre-cast f32 values
         if self.block % 128:
             return f"block {self.block} is not a multiple of 128"
+        from repro.kernels import pack
+        gap = pack.pack_vmem_gap(self.block, self.kb)
+        if gap is not None:
+            return gap
         if self.val_dtype != "float32":
             return f"{self.val_dtype} wire values"
         return None
@@ -1084,13 +1089,17 @@ def fused_pack(lw: LeafWire, g: Array, h: Array, lam: float, *,
     pipelined trainer just stops waiting for them).
     """
     mode = _kernel_mode(kernel)
-    if mode in ("pallas", "interpret") and lw.block % 128 != 0:
-        # the Pallas kernel tiles 128-lane slabs; other block sizes take the
-        # bit-identical oracle.  Only an *explicit* per-call request errors.
-        if kernel in ("pallas", "interpret"):
-            raise ValueError(
-                f"Pallas pack kernel requires block % 128 == 0, got {lw.block}")
-        mode = "oracle"
+    if mode in ("pallas", "interpret"):
+        from repro.kernels import pack
+        # the Pallas kernel tiles 128-lane slabs, at least 128 blocks a grid
+        # step; other leaves take the bit-identical oracle.  Only an
+        # *explicit* per-call request errors.
+        gap = (f"requires block % 128 == 0, got {lw.block}" if lw.block % 128
+               else pack.pack_vmem_gap(lw.block, lw.kb))
+        if gap is not None:
+            if kernel in ("pallas", "interpret"):
+                raise ValueError(f"Pallas pack kernel {gap}")
+            mode = "oracle"
     if mode in ("pallas", "interpret"):
         from repro.kernels import ops
         return ops.efbv_pack_update(g, h, float(lam), block=lw.block,

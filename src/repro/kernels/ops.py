@@ -85,12 +85,13 @@ def efbv_pack_update(g: Array, h: Array, lam: float, block: int = 1024,
 
     Returns ((values, indices), h') with values/indices of shape (nb, kb),
     nb = ceil(g.size / block) -- the same payload layout as
-    ``BlockTopK.encode`` (rows added for TILE_NB alignment are sliced off).
+    ``BlockTopK.encode``.  The kernel's grid may end in a ragged step, so
+    only the streaming variant pads rows (to STREAM_TILE_NB, sliced off).
     ``stream=True`` selects the async-copy kernel variant (the payload slab
     DMAs toward HBM while the h update computes); bit-identical payloads.
     """
     interpret = _interpret_default() if interpret is None else interpret
-    tile = KP.STREAM_TILE_NB if stream else K.TILE_NB
+    tile = KP.STREAM_TILE_NB if stream else 1
     gp, d_len, shape = _to_slabs(g, block, tile)
     # h keeps its own dtype: the kernel subtracts in f32, so pre-rounding h
     # to g.dtype would break bit-identity with the jnp oracle on mixed dtypes

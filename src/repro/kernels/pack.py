@@ -28,6 +28,7 @@ backends -- the differential harness in tests/harness.py pins this.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +43,25 @@ QS_TILE_NB = 32  # rows per grid step for int8 outputs (min int8 tile: 32x128)
 # rows per grid step of the streaming pack kernel: its payload is DMA'd
 # transposed, (kb, rows), and a DMA's minor dimension must fill 128 lanes
 STREAM_TILE_NB = 128
+# candidate rows per grid step of the non-streaming pack kernel, widest
+# first (``pack_tile``): multiples of 128, so the transposed tile and the
+# (kb, rows) payload block are lane-dense
+PACK_TILES = (1024, 512, 256, 128)
+# VMEM bytes per element of a tile row: g, h and h_out double-buffered
+# (f32) and about six (block, rows) f32 intermediates of the selection
+_PACK_ROW_BYTES = 3 * 2 * 4 + 6 * 4
+# VMEM bytes per kept entry of a tile row: the f32 values and int32 index
+# payload blocks double-buffered, and the kb (1, rows) rounds of each held
+# on 8 sublanes until they are concatenated.  Against Mosaic's own scoped
+# allocation for v5e (35-38 B per element, about 40 per kept entry, at
+# blocks 256-4096 and kb 16-256) the estimate is an upper bound
+_PACK_KB_BYTES = 2 * 2 * 4 + 2 * 8 * 4
+# v5e's default scoped VMEM limit: the widest tile whose working set fits
+# it is taken, and the narrowest tile asks for more where it needs more
+SCOPED_VMEM = 16 * 2 ** 20
+# the most of v5e's 128 MiB of VMEM a pack kernel asks for: a block so wide
+# that even the narrowest tile needs more has no kernel
+PACK_VMEM_MAX = 96 * 2 ** 20
 
 
 def _out(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
@@ -51,56 +71,69 @@ def _out(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _select_block_topk(delta, kb: int, axis: int = 1):
-    """The shared selection core of both pack kernels.  Blocks run along
-    ``axis`` of ``delta``; with axis=1 it returns (vals f32 (rows, kb), cols
-    f32 (rows, kb), selected bool (rows, block)), with axis=0 the transposed
-    (kb, rows) payloads of a (block, rows) input.  One body keeps the
-    streaming and non-streaming variants bit-identical by construction."""
-    mag = jnp.abs(delta)
-    block = mag.shape[axis]
+def _select_block_topk(delta_t, kb: int):
+    """The shared selection core of both pack kernels, on a transposed tile:
+    each block runs down axis 0 of the (block, rows) ``delta_t``, one block
+    per lane.  Returns the lane-dense payloads vals f32 (kb, rows) and cols
+    f32 (kb, rows), and selected bool (block, rows).
+
+    A round's reduction over a block is an elementwise max (or min) across
+    the tile's vregs and one 8-sublane reduce, and the rows are independent
+    lanes: a wide tile gives each round many vregs of independent work.
+    One body keeps the streaming and non-streaming kernels bit-identical by
+    construction."""
+    mag = jnp.abs(delta_t)
+    block = mag.shape[0]
     # column indices compared in f32 (exact for block < 2**24), the same
     # compares the jnp oracle's tie-breaking is pinned against.  Mosaic's
     # tpu.iota is integer-only, so the iota is built as int32 and converted;
     # cumsum has no Mosaic lowering, hence the min-reduction tie-break below
-    cols = jax.lax.broadcasted_iota(jnp.int32, mag.shape, axis).astype(
+    cols = jax.lax.broadcasted_iota(jnp.int32, mag.shape, 0).astype(
         jnp.float32)
 
-    # python-unrolled over the (static, small) kb: payload columns are
+    # python-unrolled over the (static, small) kb: payload rows are
     # assembled with one concatenate -- loop-carried dynamic_update_slice has
-    # no Mosaic lowering, and the unroll keeps everything elementwise+reduce
-    selected = jnp.zeros(mag.shape, jnp.bool_)
-    v_cols, c_cols = [], []
+    # no Mosaic lowering, and the unroll keeps everything elementwise+reduce.
+    # score carries the selection: a kept entry's score is -inf, which no
+    # magnitude is, so the selected mask is (score == -inf) at the end
+    score = mag
+    v_rows, c_rows = [], []
     for _ in range(kb):
-        score = jnp.where(selected, -jnp.inf, mag)
-        m = jnp.max(score, axis=axis, keepdims=True)
-        # m != -inf guards the all-selected row (kb == block); spelled as a
-        # compare because isfinite has no Pallas TPU lowering
-        is_m = (score == m) & (m != -jnp.inf)
+        m = jnp.max(score, axis=0, keepdims=True)
         # exact first-index tie-breaking == jax.lax.top_k's stable order:
-        # the smallest column index among the maxima
-        cmin = jnp.min(jnp.where(is_m, cols, float(block)), axis=axis,
+        # the smallest column index among the maxima.  m == -inf is the
+        # all-selected block (kb == block), spelled as a compare because
+        # isfinite has no Pallas TPU lowering; cmin = block keeps nothing
+        cmin = jnp.min(jnp.where(score == m, cols, float(block)), axis=0,
                        keepdims=True)
-        first = is_m & (cols == cmin)
-        v_cols.append(jnp.sum(jnp.where(first, delta, 0.0), axis=axis,
-                              keepdims=True))
-        c_cols.append(jnp.max(jnp.where(first, cols, 0.0), axis=axis,
-                              keepdims=True))
-        selected = selected | first
-    return (jnp.concatenate(v_cols, axis=axis),
-            jnp.concatenate(c_cols, axis=axis), selected)
+        cmin = jnp.where(m != -jnp.inf, cmin, float(block))
+        first = cols == cmin
+        # exactly one column is first where cmin < block, none elsewhere.
+        # The value is taken by a max over -inf fill, which returns it bit
+        # for bit (a sum's +0.0 start would turn a kept -0.0 into +0.0)
+        kept = cmin < block
+        v = jnp.max(jnp.where(first, delta_t, -jnp.inf), axis=0,
+                    keepdims=True)
+        v_rows.append(jnp.where(kept, v, 0.0))
+        c_rows.append(jnp.where(kept, cmin, 0.0))
+        score = jnp.where(first, -jnp.inf, score)
+    return (jnp.concatenate(v_rows, axis=0),
+            jnp.concatenate(c_rows, axis=0), score == -jnp.inf)
 
 
 def _pack_update_kernel(g_ref, h_ref, vals_ref, idx_ref, h_out_ref, *,
                         kb: int, lam: float):
+    """One (rows, block) slab of g and h: selection on the transposed tile,
+    the payload written lane-dense as a (kb, rows) block, h_out in the
+    slab's own layout."""
     g = g_ref[...]
     h = h_ref[...]
     # subtract in f32: bit-identical between interpret mode and TPU lowering
-    delta = g.astype(jnp.float32) - h.astype(jnp.float32)
-    vals, cols, selected = _select_block_topk(delta, kb)
+    delta_t = (g.astype(jnp.float32) - h.astype(jnp.float32)).T
+    vals, cols, selected = _select_block_topk(delta_t, kb)
     vals_ref[...] = vals.astype(vals_ref.dtype)
     idx_ref[...] = cols.astype(jnp.int32)
-    d = jnp.where(selected, delta, 0.0)
+    d = jnp.where(selected, delta_t, 0.0).T
     h_out_ref[...] = (h.astype(jnp.float32) + lam * d).astype(h_out_ref.dtype)
 
 
@@ -110,18 +143,14 @@ def _pack_update_stream_kernel(g_ref, h_ref, vals_ref, idx_ref, h_out_ref,
     DMA'd toward its HBM output (vals_ref/idx_ref live in pl.ANY) while
     the h update still computes -- the wire bytes of this grid step stream
     out under the remaining compute instead of waiting for the step's
-    epilogue.
-
-    Selection runs on the transposed (block, rows) tile, so the payload
-    comes out lane-dense as (kb, rows): Mosaic refuses a DMA whose minor
-    dimension (kb) is narrower than the 128-lane tiling.  Transposes and the
-    selection are exact, so the results are the non-streaming kernel's,
-    bit for bit."""
+    epilogue.  Mosaic refuses a DMA whose minor dimension is narrower than
+    the 128-lane tiling, which the lane-dense (kb, rows) payload meets.
+    Same body as the non-streaming kernel, so the same bits."""
     t = pl.program_id(0)
     g = g_ref[...]
     h = h_ref[...]
     delta_t = (g.astype(jnp.float32) - h.astype(jnp.float32)).T
-    vals, cols, selected = _select_block_topk(delta_t, kb, axis=0)
+    vals, cols, selected = _select_block_topk(delta_t, kb)
     v_scr[...] = vals.astype(v_scr.dtype)
     i_scr[...] = cols.astype(jnp.int32)
     rows = v_scr.shape[1]
@@ -139,51 +168,88 @@ def _pack_update_stream_kernel(g_ref, h_ref, vals_ref, idx_ref, h_out_ref,
     i_dma.wait()
 
 
+def pack_vmem_bytes(tile: int, block: int, kb: int) -> int:
+    """Upper estimate of the VMEM one grid step of either pack kernel holds,
+    for ``tile`` rows of ``block`` entries keeping ``kb`` of each."""
+    return tile * (block * _PACK_ROW_BYTES + kb * _PACK_KB_BYTES)
+
+
+def pack_vmem_gap(block: int, kb: int) -> Optional[str]:
+    """Why the pack kernels cannot take ``block``/``kb`` within
+    PACK_VMEM_MAX, or None.  The narrowest legal tile is 128 rows (a leaf of
+    fewer blocks takes them all, which needs less)."""
+    need = pack_vmem_bytes(STREAM_TILE_NB, block, kb)
+    if need > PACK_VMEM_MAX:
+        return (f"needs {need / 2 ** 20:.0f} MiB of VMEM for block {block}, "
+                f"kb {kb} at {STREAM_TILE_NB} rows, over its "
+                f"{PACK_VMEM_MAX // 2 ** 20} MiB")
+    return None
+
+
+def pack_tile(nb: int, block: int, kb: int) -> int:
+    """Blocks (rows) per grid step of the non-streaming pack kernel: the
+    whole leaf where it has at most 128 blocks (a block equal to the full
+    array dimension), else the widest of ``PACK_TILES`` that the leaf fills
+    and whose working set fits ``SCOPED_VMEM``, else 128 rows, the
+    narrowest the lane-dense payload allows.  ``pack_update_pallas`` raises
+    the scoped VMEM limit to the tile's working set where that passes the
+    default.  The last grid step may be ragged: rows are independent lanes
+    of the transposed tile, so out-of-bounds rows touch no kept entry, and
+    their outputs are dropped."""
+    if nb <= 128:
+        return nb
+    for tile in PACK_TILES:
+        if (tile <= nb
+                and pack_vmem_bytes(tile, block, kb) <= SCOPED_VMEM):
+            return tile
+    return PACK_TILES[-1]
+
+
 def pack_update_pallas(g2d: Array, h2d: Array, lam: float, kb: int, *,
                        interpret: bool = False, stream: bool = False):
-    """g2d/h2d: (nb, block) with block % 128 == 0 and nb % TILE_NB == 0
-    (nb % STREAM_TILE_NB == 0 with ``stream=True``).
+    """g2d/h2d: (nb, block) with block % 128 == 0 (and nb % STREAM_TILE_NB
+    == 0 with ``stream=True``); the tile comes from ``pack_tile``.
 
     Returns (values (nb, kb), indices (nb, kb) int32, h_new (nb, block)).
+    The kernels emit the payload lane-dense as (kb, nb), transposed here.
     ``stream=True`` takes the async-copy kernel (payload DMA overlaps the h
     update); results are bit-identical to the non-streaming kernel.
+    Raises ValueError where ``pack_vmem_gap`` names a gap.
     """
     nb, block = g2d.shape
-    tile = STREAM_TILE_NB if stream else TILE_NB
-    assert nb % tile == 0 and block % 128 == 0, (nb, block, tile)
+    assert block % 128 == 0, (nb, block)
     assert 0 < kb <= block, (kb, block)
-    grid = (nb // tile,)
-    slab = pl.BlockSpec((tile, block), lambda i: (i, 0))
-    out_shape = (_out((nb, kb), g2d.dtype, g2d, h2d),
-                 _out((nb, kb), jnp.int32, g2d, h2d),
-                 _out((nb, block), h2d.dtype, g2d, h2d))
+    gap = pack_vmem_gap(block, kb)
+    if gap is not None:
+        raise ValueError(gap)
     if stream:
-        vals_t, idx_t, h_new = pl.pallas_call(
-            functools.partial(_pack_update_stream_kernel, kb=kb,
-                              lam=float(lam)),
-            grid=grid,
-            in_specs=[slab, slab],
-            out_specs=(pl.BlockSpec(memory_space=pl.ANY),
-                       pl.BlockSpec(memory_space=pl.ANY),
-                       slab),
-            out_shape=(_out((kb, nb), g2d.dtype, g2d, h2d),
-                       _out((kb, nb), jnp.int32, g2d, h2d),
-                       out_shape[2]),
-            scratch_shapes=[pltpu.VMEM((kb, tile), g2d.dtype),
-                            pltpu.VMEM((kb, tile), jnp.int32),
-                            pltpu.SemaphoreType.DMA((2,))],
-            interpret=interpret,
-        )(g2d, h2d)
-        return vals_t.T, idx_t.T, h_new
-    payload = pl.BlockSpec((tile, kb), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_pack_update_kernel, kb=kb, lam=float(lam)),
-        grid=grid,
+        tile = STREAM_TILE_NB
+        assert nb % tile == 0, (nb, tile)
+        kernel = _pack_update_stream_kernel
+        payload = pl.BlockSpec(memory_space=pl.ANY)
+        scratch = [pltpu.VMEM((kb, tile), g2d.dtype),
+                   pltpu.VMEM((kb, tile), jnp.int32),
+                   pltpu.SemaphoreType.DMA((2,))]
+    else:
+        tile = pack_tile(nb, block, kb)
+        kernel = _pack_update_kernel
+        payload = pl.BlockSpec((kb, tile), lambda i: (0, i))
+        scratch = []
+    slab = pl.BlockSpec((tile, block), lambda i: (i, 0))
+    vmem_limit = max(SCOPED_VMEM, pack_vmem_bytes(tile, block, kb))
+    vals_t, idx_t, h_new = pl.pallas_call(
+        functools.partial(kernel, kb=kb, lam=float(lam)),
+        grid=(pl.cdiv(nb, tile),),
         in_specs=[slab, slab],
         out_specs=(payload, payload, slab),
-        out_shape=out_shape,
+        out_shape=(_out((kb, nb), g2d.dtype, g2d, h2d),
+                   _out((kb, nb), jnp.int32, g2d, h2d),
+                   _out((nb, block), h2d.dtype, g2d, h2d)),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(g2d, h2d)
+    return vals_t.T, idx_t.T, h_new
 
 
 # ---------------------------------------------------------------------------

@@ -36,15 +36,52 @@ CONFIGS = [
 # payload producers are bit-identical
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("d,block,kb", CONFIGS)
-def test_fused_pack_matches_oracle(d, block, kb):
+def _case(d, block, kb, inputs="normal"):
+    suffix = "" if inputs == "normal" else f"-{inputs}"
+    return pytest.param(d, block, kb, inputs, id=f"{d}-{block}-{kb}{suffix}")
+
+
+# the pack kernel's tiles on top of CONFIGS (whose leaves each fit one tile):
+# block counts that are not a multiple of the tile (a ragged last grid
+# step), a one-block leaf, and inputs whose magnitudes are all tied
+PACK_CONFIGS = [_case(*c) for c in CONFIGS] + [
+    _case(290 * 128 - 50, 128, 8),    # 290 blocks: tile 256, 34 left
+    _case(129 * 128, 128, 4),         # 129 blocks: tile 128, 1 left
+    _case(100, 128, 4),               # one padded block
+    _case(6 * 256, 256, 16, "tied"),  # |delta| 0.5 or +-0.0 throughout
+    _case(300 * 128, 128, 8, "tied"),  # the same over a ragged grid
+]
+
+
+def _pack_inputs(d, block, inputs):
+    if inputs == "normal":
+        return (jax.random.normal(KEY, (d,)),
+                jax.random.normal(jax.random.key(1), (d,)))
+    # random signs; every other block all zeros, so those blocks tie at
+    # magnitude 0 with deltas of both signs (-0.0 - 0.0 is -0.0)
+    sign = jnp.where(jax.random.bernoulli(KEY, 0.5, (d,)), 1.0, -1.0)
+    size = jnp.where((jnp.arange(d) // block) % 2 == 0, 0.5, 0.0)
+    return sign * size, jnp.zeros((d,))
+
+
+def _float_bits(tree):
+    """Float leaves as their bit patterns: assert_array_equal takes -0.0
+    for +0.0, the bits do not."""
+    return [np.asarray(x).view(np.uint32) if x.dtype == jnp.float32
+            else np.asarray(x) for x in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("d,block,kb,inputs", PACK_CONFIGS)
+def test_fused_pack_matches_oracle(d, block, kb, inputs):
     lw = wire.LeafWire(shape=(d,), size=d, block=block, kb=kb)
-    g = jax.random.normal(KEY, (d,))
-    h = jax.random.normal(jax.random.key(1), (d,))
+    g, h = _pack_inputs(d, block, inputs)
     ref = wire.fused_pack(lw, g, h, 0.37, kernel="oracle")
     for impl in available_pack_impls():
-        got = wire.fused_pack(lw, g, h, 0.37, kernel=impl)
-        assert_bit_identical(got, ref, f"impl={impl} cfg={(d, block, kb)}")
+        for stream in (False, True):
+            got = wire.fused_pack(lw, g, h, 0.37, kernel=impl, stream=stream)
+            ctx = f"impl={impl} stream={stream} cfg={(d, block, kb, inputs)}"
+            assert_bit_identical(got, ref, ctx)
+            assert_bit_identical(_float_bits(got), _float_bits(ref), ctx)
 
 
 def test_fused_pack_matches_oracle_on_ties():
@@ -80,6 +117,20 @@ def test_fused_pack_unaligned_block_falls_back_to_oracle():
     got = wire.fused_pack(lw, g, h, 0.5)  # auto
     assert_bit_identical(got, ref, "auto fallback, block=100")
     with pytest.raises(ValueError, match="block % 128"):
+        wire.fused_pack(lw, g, h, 0.5, kernel="interpret")
+
+
+def test_fused_pack_wide_block_falls_back_to_oracle():
+    """A block so wide that 128 rows of it pass the pack kernel's VMEM has
+    no kernel: auto dispatch takes the oracle, explicit requests error."""
+    block = 32768
+    lw = wire.LeafWire(shape=(block,), size=block, block=block, kb=16)
+    g = jax.random.normal(KEY, (block,))
+    h = jnp.zeros((block,))
+    ref = wire.fused_pack(lw, g, h, 0.5, kernel="oracle")
+    got = wire.fused_pack(lw, g, h, 0.5)  # auto
+    assert_bit_identical(got, ref, "auto fallback, block=32768")
+    with pytest.raises(ValueError, match="MiB of VMEM"):
         wire.fused_pack(lw, g, h, 0.5, kernel="interpret")
 
 
@@ -416,7 +467,10 @@ def test_bidirectional_compressed_downlink_tracks_model(trainer):
      "block 100 is not a multiple of 128"),
     (BlockTopK(256, 16), "bfloat16", (4, 256), "bfloat16 wire values"),
     (RandK(1000), "float32", (2 ** 24,), "size 16777216 >= 2**24"),
-], ids=["fits", "block", "bf16", "randk-size"])
+    (BlockTopK(32768, 16), "float32", (32768,),
+     "needs 192 MiB of VMEM for block 32768, kb 16 at 128 rows, over its "
+     "96 MiB"),
+], ids=["fits", "block", "bf16", "randk-size", "vmem"])
 def test_kernel_gaps_name_each_oracle_leaf(comp, wire_dtype, leaf, reason):
     """Leaves whose codec has a Pallas kernel they cannot use are named with
     the reason: under kernel 'auto' they take the jnp oracle on a TPU too."""
