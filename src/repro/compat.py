@@ -1,7 +1,7 @@
 """The few jax calls this repo makes with fixed arguments, in one place.
 
 Every mesh is GSPMD-auto on all axes, every shard_map is manual over the
-worker axes only, and the MoE sharding hints read the ambient abstract
+worker axes only, and the Pallas kernel wrappers read the ambient abstract
 mesh; these helpers spell those conventions once.
 """
 

@@ -15,6 +15,7 @@ from repro.models.config import ModelConfig
 ARCHS = [
     "minitron-8b",
     "granite-moe-3b-a800m",
+    "granite-moe-3b-a800m-1chip",
     "mamba2-130m",
     "phi3-medium-14b",
     "qwen2-vl-2b",

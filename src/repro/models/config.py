@@ -29,11 +29,21 @@ class ModelConfig:
     attn_window: int = 0        # sliding-window size; 0 = full attention
     tie_embeddings: bool = False
 
-    # MoE
+    # MoE: the router spans n_experts; this chip holds the first
+    # n_experts_held of them (0: all), the rest live on other chips
     n_experts: int = 0
+    n_experts_held: int = 0
     experts_per_tok: int = 0
-    capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+
+    # Granite's scalar multipliers (1.0 / 0.0: off): the embedding times
+    # embedding_multiplier, each attention and MLP/MoE branch times
+    # residual_multiplier before its residual add, attention scores times
+    # attention_multiplier (0: 1 / sqrt(head_dim)), logits over logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
 
     # SSM (mamba2 / SSD)
     ssm_state: int = 0
@@ -60,8 +70,6 @@ class ModelConfig:
     # 'flat' (shard anyway; best for memory-bound) or 'replicate' (no score
     # collectives; best for collective-bound) -- see layers._head_spec
     attn_shard_policy: str = "flat"
-    # MoE dispatch groups (0 = one per batch row; §Perf iteration 2)
-    moe_groups: int = 0
 
     # numerics / memory
     norm_eps: float = 1e-5
@@ -73,6 +81,9 @@ class ModelConfig:
         if self.head_dim:
             return self.head_dim
         return self.d_model // max(self.n_heads, 1)
+
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     def ssm_heads(self) -> int:
         return (self.ssm_expand * self.d_model) // self.ssm_head_dim
@@ -92,7 +103,8 @@ class ModelConfig:
         mlp = 3 * d * ff  # swiglu: gate + up + down
         norms = 2 * d
         if self.family == "moe":
-            mlp = self.n_experts * 3 * d * ff + d * self.n_experts  # experts + router
+            # held experts + the router over all of them
+            mlp = self.experts_held() * 3 * d * ff + d * self.n_experts
         if self.family == "ssm":
             di, st, nh = self.d_inner(), self.ssm_state, self.ssm_heads()
             in_p = d * (2 * di + 2 * st + nh)
@@ -124,6 +136,6 @@ class ModelConfig:
             return self.param_count()
         d, ff = self.d_model, self.d_ff
         dense_total = self.param_count()
-        all_experts = self.n_experts * 3 * d * ff * self.n_layers
+        all_experts = self.experts_held() * 3 * d * ff * self.n_layers
         active = self.experts_per_tok * 3 * d * ff * self.n_layers
         return dense_total - all_experts + active

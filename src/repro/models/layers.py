@@ -177,14 +177,16 @@ def _project_qkv(p, x, n_heads, n_kv, hd):
             v.reshape(B, S, n_kv, hd))
 
 
-def _sdpa(q: Array, k: Array, v: Array, mask: Optional[Array]) -> Array:
-    """Grouped scaled-dot-product attention.
-    q: (B, Sq, H, hd); k, v: (B, Sk, K, hd); H = K * G."""
+def _sdpa(q: Array, k: Array, v: Array, mask: Optional[Array],
+          scale: Optional[float] = None) -> Array:
+    """Grouped scaled-dot-product attention, scores times ``scale`` (None:
+    over sqrt(hd)).  q: (B, Sq, H, hd); k, v: (B, Sk, K, hd); H = K * G."""
     B, Sq, H, hd = q.shape
     K = k.shape[2]
     G = H // K
     qg = q.reshape(B, Sq, K, G, hd)
-    scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, k) / math.sqrt(hd)
+    scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, k)
+    scores = scores / math.sqrt(hd) if scale is None else scores * scale
     scores = scores.astype(jnp.float32)
     if mask is not None:
         scores = jnp.where(mask, scores, -1e30)
@@ -194,7 +196,7 @@ def _sdpa(q: Array, k: Array, v: Array, mask: Optional[Array]) -> Array:
 
 
 def _sdpa_chunked(q: Array, k: Array, v: Array, *, window: int = 0,
-                  chunk: int = 1024) -> Array:
+                  chunk: int = 1024, scale: Optional[float] = None) -> Array:
     """Flash-style attention: lax.scan over KV chunks with an online softmax.
 
     §Perf iteration 3: the direct SDPA materializes (B, K, G, S, S) f32 score
@@ -209,7 +211,8 @@ def _sdpa_chunked(q: Array, k: Array, v: Array, *, window: int = 0,
     Sk = nc * chunk
     kp = jnp.pad(k, ((0, 0), (0, Sk - k.shape[1]), (0, 0), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, Sk - v.shape[1]), (0, 0), (0, 0)))
-    qg = (q.reshape(B, Sq, K, G, hd) / math.sqrt(hd)).astype(q.dtype)
+    qg = q.reshape(B, Sq, K, G, hd)
+    qg = (qg / math.sqrt(hd) if scale is None else qg * scale).astype(q.dtype)
     kc = kp.reshape(B, nc, chunk, K, hd)
     vc = vp.reshape(B, nc, chunk, K, hd)
     qi = jnp.arange(Sq)
@@ -255,11 +258,12 @@ def attention(p, x: Array, *, n_heads: int, n_kv: int, hd: int,
               positions: Array, theta: float, window: int = 0,
               mrope_sections: Sequence[int] = (), causal: bool = True,
               kv: Optional[Tuple[Array, Array]] = None,
-              impl: str = "direct") -> Array:
+              impl: str = "direct", scale: Optional[float] = None) -> Array:
     """Full-sequence attention (training / prefill).
 
     kv: optional externally-provided (k, v) for cross-attention.
-    impl: 'direct' (materialized scores) or 'chunked' (online softmax)."""
+    impl: 'direct' (materialized scores) or 'chunked' (online softmax).
+    scale: the scores' factor; None is 1 / sqrt(hd)."""
     B, S, _ = x.shape
     q, k, v = _project_qkv(p, x, n_heads, n_kv, hd)
     if kv is not None:
@@ -274,16 +278,17 @@ def attention(p, x: Array, *, n_heads: int, n_kv: int, hd: int,
         k = apply_rope(k, pos2, theta)
     if impl == "chunked" and causal and kv is None:
         out = _sdpa_chunked(q, k, v, window=window,
-                            chunk=min(1024, k.shape[1]))
+                            chunk=min(1024, k.shape[1]), scale=scale)
     else:
         mask = causal_mask(S, k.shape[1], window) if causal else None
-        out = _sdpa(q, k, v, mask)
+        out = _sdpa(q, k, v, mask, scale)
     return out.reshape(B, S, n_heads * hd) @ p["wo"].astype(x.dtype)
 
 
 def attention_decode(p, x: Array, cache_k: Array, cache_v: Array, pos: Array,
                      *, n_heads: int, n_kv: int, hd: int, theta: float,
-                     window: int = 0, mrope_sections: Sequence[int] = ()
+                     window: int = 0, mrope_sections: Sequence[int] = (),
+                     scale: Optional[float] = None
                      ) -> Tuple[Array, Array, Array]:
     """One-token decode with a KV cache.
 
@@ -314,7 +319,8 @@ def attention_decode(p, x: Array, cache_k: Array, cache_v: Array, pos: Array,
     else:
         valid = ki <= pos
     mask = valid[None, None, None, None, :]  # (1,1,1,1,C)
-    out = _sdpa(q, cache_k.astype(q.dtype), cache_v.astype(q.dtype), mask)
+    out = _sdpa(q, cache_k.astype(q.dtype), cache_v.astype(q.dtype), mask,
+                scale)
     out = out.reshape(B, 1, n_heads * hd) @ p["wo"].astype(x.dtype)
     return out, cache_k, cache_v
 

@@ -109,7 +109,8 @@ class Model:
                 attn, attn_s = L.attention_init(k1, d, cfg.n_heads, cfg.n_kv_heads,
                                                 hd, cfg.qkv_bias,
                                                 shard_policy=cfg.attn_shard_policy)
-                moe, moe_s = MOE.moe_init(k2, d, ff, cfg.n_experts)
+                moe, moe_s = MOE.moe_init(k2, d, ff, cfg.n_experts,
+                                          cfg.n_experts_held)
                 ln1, _ = L.rmsnorm_init(d)
                 ln2, _ = L.rmsnorm_init(d)
                 return ({"attn": attn, "moe": moe, "ln1": ln1, "ln2": ln2},
@@ -249,41 +250,65 @@ class Model:
             pos3 = jnp.stack([tpos, hpos, wpos])[:, None, :].repeat(B, axis=1)
             return h, pos3
 
-        h = tok_emb[batch["tokens"]]
+        h = self._scale_embedding(tok_emb[batch["tokens"]])
         B, S, _ = h.shape
         pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         if cfg.mrope_sections:
             pos = jnp.broadcast_to(pos, (3, B, S))
         return h, pos
 
+    def _scale_embedding(self, h: Array) -> Array:
+        m = self.cfg.embedding_multiplier
+        return h if m == 1.0 else h * m
+
+    def _residual(self, h: Array, y: Array) -> Array:
+        """A decoder branch's output added to the residual stream."""
+        m = self.cfg.residual_multiplier
+        return h + y if m == 1.0 else h + y * m
+
+    def _logits(self, params, h: Array) -> Array:
+        cfg = self.cfg
+        h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
+        logits = h @ head.astype(h.dtype)
+        s = cfg.logits_scaling
+        return logits if s == 1.0 else logits / s
+
     def _decoder_blocks(self, params, h: Array, positions,
-                        enc_out: Optional[Array] = None) -> Tuple[Array, Array]:
-        """Scan the stacked decoder blocks.  Returns (hidden, aux_loss)."""
+                        enc_out: Optional[Array] = None
+                        ) -> Tuple[Array, Array, Dict[str, Array]]:
+        """Scan the stacked decoder blocks.  Returns (hidden, aux_loss,
+        routing statistics: empty but for the moe family)."""
         cfg = self.cfg
         hd = cfg.hd()
         attn_kw = dict(n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, hd=hd,
                        positions=positions, theta=cfg.rope_theta,
                        window=cfg.attn_window,
                        mrope_sections=cfg.mrope_sections,
-                       impl=cfg.attn_impl)
+                       impl=cfg.attn_impl,
+                       scale=cfg.attention_multiplier or None)
+        res = self._residual
 
         if cfg.family in ("dense", "vlm"):
             def block(carry, lp):
                 h, aux = carry
-                h = h + L.attention(lp["attn"], L.rmsnorm(h, lp["ln1"], cfg.norm_eps),
-                                    **attn_kw)
-                h = h + L.swiglu(lp["mlp"], L.rmsnorm(h, lp["ln2"], cfg.norm_eps))
+                h = res(h, L.attention(lp["attn"],
+                                       L.rmsnorm(h, lp["ln1"], cfg.norm_eps),
+                                       **attn_kw))
+                h = res(h, L.swiglu(lp["mlp"],
+                                    L.rmsnorm(h, lp["ln2"], cfg.norm_eps)))
                 return (h, aux), None
         elif cfg.family == "moe":
             def block(carry, lp):
                 h, aux = carry
-                h = h + L.attention(lp["attn"], L.rmsnorm(h, lp["ln1"], cfg.norm_eps),
-                                    **attn_kw)
-                y, a = MOE.moe_apply(lp["moe"], L.rmsnorm(h, lp["ln2"], cfg.norm_eps),
-                                     n_experts=cfg.n_experts, k=cfg.experts_per_tok,
-                                     capacity_factor=cfg.capacity_factor,
-                                     groups=cfg.moe_groups)
-                return (h + y, aux + a), None
+                h = res(h, L.attention(lp["attn"],
+                                       L.rmsnorm(h, lp["ln1"], cfg.norm_eps),
+                                       **attn_kw))
+                y, st = MOE.moe_apply(lp["moe"],
+                                      L.rmsnorm(h, lp["ln2"], cfg.norm_eps),
+                                      n_experts=cfg.n_experts,
+                                      k=cfg.experts_per_tok)
+                return (res(h, y), aux), st
         elif cfg.family == "ssm":
             def block(carry, lp):
                 h, aux = carry
@@ -343,8 +368,14 @@ class Model:
             xs = (params["layers"], jnp.arange(cfg.n_layers))
         else:
             xs = params["layers"]
-        (h, aux), _ = jax.lax.scan(block, (h, aux0), xs)
-        return h, aux
+        (h, aux), per_layer = jax.lax.scan(block, (h, aux0), xs)
+        if cfg.family != "moe":
+            return h, aux, {}
+        # the load-balancing loss averaged over the layers; the counters of
+        # every layer's routing
+        return h, jnp.mean(per_layer["aux"]), {
+            "moe_held_rows": jnp.sum(per_layer["held_rows"]).astype(jnp.float32),
+            "moe_load_max_over_mean": jnp.max(per_layer["load"])}
 
     def _encode(self, params, frames: Array) -> Array:
         """Whisper-style encoder over stub frame embeddings (B, F, d)."""
@@ -368,6 +399,10 @@ class Model:
 
     def forward(self, params, batch) -> Tuple[Array, Array]:
         """Full-sequence forward -> (logits (B,S,V), aux loss)."""
+        logits, aux, _ = self._forward(params, batch)
+        return logits, aux
+
+    def _forward(self, params, batch) -> Tuple[Array, Array, Dict[str, Array]]:
         cfg = self.cfg
         adt = jnp.dtype(cfg.activation_dtype)
         enc_out = None
@@ -379,11 +414,8 @@ class Model:
             pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         else:
             h, pos = self._embed_inputs(params, batch)
-        h, aux = self._decoder_blocks(params, h, pos, enc_out)
-        h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-        logits = h @ head.astype(h.dtype)
-        return logits, aux
+        h, aux, stats = self._decoder_blocks(params, h, pos, enc_out)
+        return self._logits(params, h), aux, stats
 
     def prefill(self, params, batch) -> Array:
         """Inference prefill: full-sequence forward, returns last-position
@@ -415,7 +447,7 @@ class Model:
 
     def loss(self, params, batch) -> Tuple[Array, Dict[str, Array]]:
         cfg = self.cfg
-        logits, aux = self.forward(params, batch)
+        logits, aux, stats = self._forward(params, batch)
         labels = batch["labels"]
         if cfg.family == "vlm" and "vision_embeds" in batch:
             # no loss on the vision span
@@ -424,7 +456,7 @@ class Model:
             labels = jnp.concatenate([pad, labels], axis=1)
         ce, ntok = cross_entropy(logits, labels)
         total = ce + cfg.router_aux_weight * aux
-        return total, {"ce": ce, "aux_loss": aux}
+        return total, {"ce": ce, "aux_loss": aux, **stats}
 
     # ------------------------------------------------------------- serving
 
@@ -496,7 +528,7 @@ class Model:
         cfg = self.cfg
         adt = jnp.dtype(cfg.activation_dtype)
         hd = cfg.hd()
-        h = params["embed"].astype(adt)[token]  # (B,1,d)
+        h = self._scale_embedding(params["embed"].astype(adt)[token])  # (B,1,d)
         if cfg.family == "encdec":
             pe = _sinusoid_at(jnp.asarray(pos, jnp.float32)[None, None, None],
                               cfg.d_model)[0]
@@ -507,8 +539,9 @@ class Model:
                 lp["attn"], L.rmsnorm(h, lp["ln1"], cfg.norm_eps), ck, cv, pos,
                 n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, hd=hd,
                 theta=cfg.rope_theta, window=cfg.attn_window,
-                mrope_sections=cfg.mrope_sections)
-            return h + y, ck, cv
+                mrope_sections=cfg.mrope_sections,
+                scale=cfg.attention_multiplier or None)
+            return self._residual(h, y), ck, cv
 
         if cfg.family in ("dense", "vlm", "moe"):
             def block(h, xs):
@@ -517,12 +550,10 @@ class Model:
                 hn = L.rmsnorm(h, lp["ln2"], cfg.norm_eps)
                 if cfg.family == "moe":
                     y, _ = MOE.moe_apply(lp["moe"], hn, n_experts=cfg.n_experts,
-                                         k=cfg.experts_per_tok,
-                                         capacity_factor=cfg.capacity_factor,
-                                         groups=cfg.moe_groups)
+                                         k=cfg.experts_per_tok)
                 else:
                     y = L.swiglu(lp["mlp"], hn)
-                return h + y, (ck, cv)
+                return self._residual(h, y), (ck, cv)
 
             h, (ks, vs) = jax.lax.scan(
                 lambda c, xs: block(c, xs), h,
@@ -597,10 +628,7 @@ class Model:
         else:
             raise ValueError(cfg.family)
 
-        h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-        logits = h @ head.astype(h.dtype)
-        return logits, cache
+        return self._logits(params, h), cache
 
 
 def build_model(cfg: ModelConfig) -> Model:

@@ -1,14 +1,16 @@
-"""Mixture-of-Experts layer: top-k softmax router + capacity-bounded
-scatter/gather dispatch (no O(T*E*C) one-hot tensors) + load-balance aux loss.
+"""Mixture-of-Experts layer holding a share of its experts: a router over
+all experts, dropless routing sorted by expert, grouped products over the
+experts held here, and the load-balancing loss.
 
-Expert weights are stacked on a leading E axis and expert-parallel over the
-'model' mesh axis when E divides it (dbrx: 16 experts over 16-way model axis
--> one expert per shard); otherwise the per-expert FFN dim is sharded
-(granite: 40 experts, d_ff=512 -> ff sharded).
+A layer whose experts are divided over several chips (expert parallelism)
+holds ``held`` of its ``n_experts`` here, ids 0..held-1 of the router's
+outputs; with ``held == n_experts`` it is the whole layer.  Expert weights
+are stacked on a leading axis of the held experts.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Tuple
 
@@ -16,30 +18,34 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.models.layers import MODEL_AXIS_SIZE, _init, auto_spec
+from repro.kernels.gmm import gmm
+from repro.models.layers import _init, auto_spec
 
 Array = jax.Array
 
 
-def moe_init(key, d: int, ff: int, n_experts: int) -> Tuple[Dict, Dict]:
+def moe_init(key, d: int, ff: int, n_experts: int,
+             held: int = 0) -> Tuple[Dict, Dict]:
+    """The router over all ``n_experts`` and the ``held`` experts of this
+    chip (0: all of them): matrices N(0, 1) / sqrt(fan_in), the router
+    N(0, 0.02^2)."""
+    held = held or n_experts
     ks = jax.random.split(key, 4)
     params = {
         "router": _init(ks[0], (d, n_experts), scale=0.02),
-        "wg": _init(ks[1], (n_experts, d, ff)),
-        "wu": _init(ks[2], (n_experts, d, ff)),
-        "wd": _init(ks[3], (n_experts, ff, d), scale=1.0 / math.sqrt(ff)),
+        "wg": _init(ks[1], (held, d, ff), scale=1.0 / math.sqrt(d)),
+        "wu": _init(ks[2], (held, d, ff), scale=1.0 / math.sqrt(d)),
+        "wd": _init(ks[3], (held, ff, d), scale=1.0 / math.sqrt(ff)),
     }
-    # Expert-parallel over 'model' ONLY when E divides it (dbrx: 16/16).
-    # When it doesn't (granite: 40), REPLICATE the (small) expert weights
-    # rather than sharding the per-expert ff dim: ff-sharded experts force a
-    # model-axis gather of the (E, C, d) token buffer every layer -- measured
-    # 4.1 TB/device on granite prefill_32k (§Perf granite I4).  Replicated
-    # weights cost 3*E*d*ff bytes once and make MoE compute group-local.
+    # stored over 'model' where the held experts divide the production axis,
+    # else replicated; the grouped products (kernels/gmm.py) run each model
+    # shard's own experts wherever the mesh's 'model' axis divides them, so
+    # stored shards are never gathered
     specs = {
         "router": P(None, None),
-        "wg": auto_spec((n_experts, d, ff), prefer=(0,)),
-        "wu": auto_spec((n_experts, d, ff), prefer=(0,)),
-        "wd": auto_spec((n_experts, ff, d), prefer=(0,)),
+        "wg": auto_spec((held, d, ff), prefer=(0,)),
+        "wu": auto_spec((held, d, ff), prefer=(0,)),
+        "wd": auto_spec((held, ff, d), prefer=(0,)),
     }
     return params, specs
 
@@ -56,15 +62,15 @@ def _is_moe_subtree(node) -> bool:
 def expert_activity_mask(moe_grads: Dict) -> Array:
     """Which experts this round's gradients actually touched.
 
-    Capacity-bounded dispatch scatters a ZERO buffer row to every expert no
-    token routed to (see :func:`_dispatch_group`), so an unrouted expert's
+    The grouped products give an expert with no rows an exactly-zero weight
+    gradient (see :mod:`repro.kernels.gmm`), so an unrouted expert's
     wg/wu/wd gradient slab is exactly zero -- its activity is readable off
     the gradients with no routing side-channel.  Returns a boolean mask of
-    shape ``(..., E)`` (leading dims = any stacked-layer axes of the expert
-    leaves, e.g. ``(L, E)`` for a stacked transformer): True where ANY of
-    the three expert slabs carries a nonzero entry.  Router gradients are
-    dense (every token differentiates through the softmax) and do not enter
-    the mask."""
+    shape ``(..., E)``, E the held experts (leading dims = any stacked-layer
+    axes of the expert leaves, e.g. ``(L, E)`` for a stacked transformer):
+    True where ANY of the three expert slabs carries a nonzero entry.
+    Router gradients are dense (every token differentiates through the
+    softmax) and do not enter the mask."""
     masks = []
     for name in EXPERT_LEAVES:
         g = moe_grads[name]
@@ -116,112 +122,97 @@ def fixed_routing_params(params):
     return walk(params)
 
 
-def _auto_axes():
-    """Names of non-'model' mesh axes currently under GSPMD (auto) control;
-    empty when no mesh is ambient or inside a fully-manual shard_map."""
-    from repro import compat
-    return compat.auto_axes_of(compat.abstract_mesh(), exclude=("model",))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _permute(x: Array, rows: Array, inverse: Array, k: int) -> Array:
+    """``x[rows // k]``: row i of the result is token ``rows[i] // k`` of x,
+    where ``rows`` is a permutation of the T * k (token, choice) assignments
+    and ``inverse`` its inverse.  The gradient gathers by ``inverse`` and sums
+    each token's k choices, so neither direction scatters."""
+    return x[rows // k]
 
 
-def _maybe_group_constraint(x: Array, G: int) -> Array:
-    """Pin the MoE dispatch-group dim to the (auto) worker axes (§Perf
-    granite iteration 3): without this, GSPMD materialized every group's
-    expert buffer on every data shard and all-reduced 4.1 TB/device of
-    grouped buffers on granite prefill_32k; with it each shard dispatches
-    only its own groups."""
-    import math as _math
-    from repro import compat
-    axes = _auto_axes()
-    if not axes:
-        return x
-    mesh = compat.abstract_mesh()
-    n = _math.prod(mesh.shape[a] for a in axes)
-    if n <= 1 or G % n:
-        return x
-    return jax.lax.with_sharding_constraint(
-        x, P(axes, *([None] * (x.ndim - 1))))
+def _permute_fwd(x, rows, inverse, k):
+    return x[rows // k], (rows, inverse)
 
 
-def _maybe_ep_constraint(x: Array, n_experts: int) -> Array:
-    """Pin the (E, C, d) expert buffer to expert-parallel sharding when E
-    divides the model axis and a mesh is ambient (§Perf dbrx iteration: the
-    unconstrained buffer replicates over 'model' and the expert-FFN outputs
-    come back via ~1 TB/device of all-reduces; constraining E makes GSPMD
-    move tokens with all-to-alls instead -- k*T*d words, ~16x less)."""
-    from repro import compat
-    mesh = compat.abstract_mesh()
-    if mesh is None or mesh.empty or "model" not in mesh.axis_names:
-        return x
-    if n_experts % mesh.shape["model"] != 0:
-        return x
-    spec = P(*(["model"] + [None] * (x.ndim - 1)))
-    return jax.lax.with_sharding_constraint(x, spec)
+def _permute_bwd(k, res, g):
+    rows, inverse = res
+    return (jnp.sum(g[inverse].reshape(-1, k, g.shape[-1]), axis=1),
+            None, None)
 
 
-def _dispatch_group(p, xt: Array, *, n_experts: int, k: int,
-                    capacity: int) -> Tuple[Array, Array]:
-    """Capacity-bounded dispatch+combine for one token group.
-    xt: (Tg, d) -> (out (Tg, d), aux)."""
-    Tg, d = xt.shape
-    logits = (xt @ p["router"].astype(xt.dtype)).astype(jnp.float32)  # (Tg, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_ids = jax.lax.top_k(probs, k)                   # (Tg, k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-
-    # load-balance aux loss (Switch-style): E * sum_e frac_tokens_e * mean_prob_e
-    me = jnp.mean(probs, axis=0)
-    ce = jnp.mean(jax.nn.one_hot(expert_ids[:, 0], n_experts), axis=0)
-    aux = n_experts * jnp.sum(me * ce)
-
-    flat_ids = expert_ids.reshape(-1)                                 # (Tg*k,)
-    onehot = jax.nn.one_hot(flat_ids, n_experts, dtype=jnp.int32)
-    pos_in_expert = (jnp.cumsum(onehot, axis=0) - onehot)[
-        jnp.arange(Tg * k), flat_ids]
-    in_cap = pos_in_expert < capacity
-    slot = jnp.where(in_cap, flat_ids * capacity + pos_in_expert,
-                     n_experts * capacity)                            # trash slot
-
-    buf = jnp.zeros((n_experts * capacity + 1, d), xt.dtype)
-    xk = jnp.repeat(xt, k, axis=0)
-    buf = buf.at[slot].add(xk)
-    eb = _maybe_ep_constraint(buf[:-1].reshape(n_experts, capacity, d), n_experts)
-
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", eb, p["wg"].astype(xt.dtype)))
-    h = h * jnp.einsum("ecd,edf->ecf", eb, p["wu"].astype(xt.dtype))
-    out_e = jnp.einsum("ecf,efd->ecd", h, p["wd"].astype(xt.dtype))
-
-    flat_out = jnp.concatenate(
-        [out_e.reshape(n_experts * capacity, d), jnp.zeros((1, d), xt.dtype)], 0)
-    ok = flat_out[slot]
-    weighted = ok * (gate_vals.reshape(-1, 1).astype(xt.dtype) *
-                     in_cap.reshape(-1, 1).astype(xt.dtype))
-    return jnp.sum(weighted.reshape(Tg, k, d), axis=1), aux
+_permute.defvjp(_permute_fwd, _permute_bwd)
 
 
-def moe_apply(p, x: Array, *, n_experts: int, k: int,
-              capacity_factor: float = 1.25,
-              groups: int = 0) -> Tuple[Array, Array]:
-    """x: (B, S, d) -> (out (B, S, d), aux load-balance loss scalar).
+@jax.custom_vjp
+def _unpermute(y: Array, rows: Array, inverse: Array) -> Array:
+    """``y[inverse]``: the sorted rows back in (token, choice) order; the
+    gradient gathers by ``rows``."""
+    return y[inverse]
 
-    Dispatch is *grouped* (§Perf iteration 2): tokens are split into
-    ``groups`` independent dispatch groups (default: one per batch row) that
-    each build their own (E, C_g, d) expert buffer.  The group dim inherits
-    the batch's data-axis sharding, so dispatch is shard-local -- the
-    ungrouped formulation scattered into one global (E*C, d) buffer which
-    GSPMD all-reduced across data shards (measured 2 x 4.1 TB/device on
-    granite prefill_32k).  Per-group capacity also matches how real MoE
-    systems bound device-local buffers.
+
+def _unpermute_fwd(y, rows, inverse):
+    return y[inverse], (rows, inverse)
+
+
+def _unpermute_bwd(res, g):
+    rows, _ = res
+    return g[rows], None, None
+
+
+_unpermute.defvjp(_unpermute_fwd, _unpermute_bwd)
+
+
+def moe_apply(p, x: Array, *, n_experts: int, k: int
+              ) -> Tuple[Array, Dict[str, Array]]:
+    """x: (B, S, d) -> (this chip's part of the layer's output (B, S, d),
+    routing statistics).
+
+    The router keeps all ``n_experts`` outputs: float32 logits, the top k,
+    gates by a softmax over those k.  The layer holds the first
+    ``p["wg"].shape[0]`` experts (ids 0..held-1) and computes only their
+    SwiGLU, each weighted by its gate: the experts held on other chips add
+    the rest of the sum there.  Routing is dropless: the T * k assignments
+    are sorted by expert id (those of experts not held last) and the held
+    experts run as grouped products over their own rows (``kernels.gmm``),
+    however unevenly the tokens fall.
+
+    Statistics: ``aux``, the load-balancing loss over every expert and all
+    k choices, E * sum_e (c_e / T) * mean_t p_te with c_e the assignments to
+    expert e and p the softmax over all E logits (k at perfect balance);
+    ``held_rows``, the assignments that reached a held expert; ``load``, the
+    busiest held expert's rows over the held experts' mean (0 with none).
     """
     B, S, d = x.shape
-    T = B * S
-    G = groups or B
-    while T % G:
-        G -= 1
-    Tg = T // G
-    capacity = max(1, int(capacity_factor * k * Tg / n_experts))
-    xg = _maybe_group_constraint(x.reshape(G, Tg, d), G)
-    out, aux = jax.vmap(
-        lambda xt: _dispatch_group(p, xt, n_experts=n_experts, k=k,
-                                   capacity=capacity))(xg)
-    out = _maybe_group_constraint(out, G)
-    return out.reshape(B, S, d), jnp.mean(aux)
+    T, held = B * S, p["wg"].shape[0]
+    xt = x.reshape(T, d)
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(xt.astype(jnp.float32),
+                         p["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)       # (T, E)
+        top, ids = jax.lax.top_k(logits, k)                          # (T, k)
+        gates = jax.nn.softmax(top, axis=-1)
+        flat = ids.reshape(-1)
+        counts = jnp.sum(jax.nn.one_hot(flat, n_experts, dtype=jnp.int32),
+                         axis=0)                                     # (E,)
+        probs = jax.nn.softmax(logits, axis=-1)
+        aux = n_experts * jnp.sum(counts.astype(jnp.float32) / T
+                                  * jnp.mean(probs, axis=0))
+        rows = jnp.argsort(jnp.minimum(flat, held), stable=True)
+        inverse = jnp.argsort(rows)
+        sizes = jnp.concatenate([counts[:held],
+                                 (T * k - jnp.sum(counts[:held]))[None]])
+        xs = _permute(xt, rows, inverse, k)                          # (T*k, d)
+    with jax.named_scope("moe.experts"):
+        dt = x.dtype
+        h = jax.nn.silu(gmm(xs, p["wg"].astype(dt), sizes)) \
+            * gmm(xs, p["wu"].astype(dt), sizes)
+        ys = gmm(h, p["wd"].astype(dt), sizes)
+    with jax.named_scope("moe.route"):
+        yk = _unpermute(ys, rows, inverse).reshape(T, k, d)
+        w = jnp.where(ids < held, gates, 0.0)
+        out = jnp.sum(yk.astype(jnp.float32) * w[..., None], axis=1).astype(dt)
+    held_rows = jnp.sum(counts[:held])
+    load = held * jnp.max(counts[:held]) / jnp.maximum(held_rows, 1)
+    return out.reshape(B, S, d), {"aux": aux, "held_rows": held_rows,
+                                  "load": load}

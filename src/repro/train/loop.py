@@ -223,8 +223,8 @@ class FinetuneLoop:
         self.state = jax.tree.map(jax.device_put, state, shardings)
 
         # the worker-side expert-sparsity hook: enforce exact-zero inactive
-        # slabs before Algorithm 1 compresses (the identity under capacity
-        # dispatch, and the contract the expert topk leaf rules rely on)
+        # slabs before Algorithm 1 compresses (the identity under dropless
+        # routing, and the contract the expert topk leaf rules rely on)
         grad_transform = (moe.zero_inactive_expert_grads
                           if self.cfg.family == "moe" else None)
         loss_fn = self.model.loss

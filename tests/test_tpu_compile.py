@@ -7,20 +7,30 @@ than the 128-lane tiling), a kernel over its VMEM or SMEM budget.  Shapes
 are qwen2-0.5b's (configs/qwen2_0_5b.py): the stacked MLP leaf of
 24 x 896 x 4864 f32 elements (block-top-k also at the embedding and the
 final norm, and at blocks 1024 and 4096), and for rand-k (whose kernel compares f32
-positions below 2**24) the stacked k/v projection of 24 x 896 x 128.
+positions below 2**24) the stacked k/v projection of 24 x 896 x 128.  The
+MoE layer's grouped products (kernels/gmm.py) compile at
+granite-moe-3b-a800m's widths (d 1536, experts of 512, 8 held) on one
+4,096-token row's 32,768 assignments, and at dbrx-132b's (d 6144, 16
+experts of 10,752, top-4) on the described host's four chips as a
+4-way model axis, where the experts are expert-parallel.
 """
 
 from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
-from repro.kernels import pack
+from repro import compat
+from repro.configs import get_config
+from repro.kernels import gmm, pack
 
 MLP_LEAF = 24 * 896 * 4864
 KV_LEAF = 24 * 896 * 128
@@ -31,10 +41,10 @@ SMALL_4096_BLOCKS = 120             # at most 128 blocks: one whole-leaf tile
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e:2x2 host, with the persistent compile
-    cache off: an entry compiled for a described chip cannot be read back
-    without one."""
+def v5e_host():
+    """The four chips of a described v5e:2x2 host, with the persistent
+    compile cache off: an entry compiled for a described chip cannot be read
+    back without one."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -47,9 +57,14 @@ def one_chip():
     cache_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", cache_on)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_host):
+    return SingleDeviceSharding(v5e_host[0])
 
 
 def _compiled_text(fn, *shapes) -> str:
@@ -93,3 +108,68 @@ def test_randk_update_compiles(one_chip):
     fn = functools.partial(pack.randk_update_pallas, scale=KV_LEAF / k,
                            lam=0.9)
     assert "tpu_custom_call" in _compiled_text(fn, slab, slab, idx)
+
+
+@pytest.mark.parametrize("k, n", [(1536, 512), (512, 1536)],
+                         ids=["gate-up", "down"])
+def test_grouped_products_compile(one_chip, k, n):
+    """The forward gmm and the backward's transposed gmm and tgmm, each a
+    Pallas kernel within the VMEM limit at the tiles ``gmm.tiling`` picks."""
+    rows = jax.ShapeDtypeStruct((4096 * 8, k), jnp.bfloat16, sharding=one_chip)
+    experts = jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((9,), jnp.int32, sharding=one_chip)
+
+    def fwd_bwd(lhs, rhs, group_sizes):
+        return jax.value_and_grad(lambda a, b: jnp.sum(
+            gmm.gmm(a, b, group_sizes, impl="kernel").astype(jnp.float32)),
+            (0, 1))(lhs, rhs)
+
+    text = _compiled_text(fwd_bwd, rows, experts, sizes)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_grouped_products_expert_parallel_compile(v5e_host):
+    """dbrx-132b's expert SwiGLU, forward and backward, inside the trainers'
+    shard_map (manual over 'data', 'model' GSPMD-auto) on a 1 x 4 mesh with
+    the 16 experts stored over 'model' as ``moe_init`` places them: each
+    chip runs its own 4 experts (9 kernels) and no expert matrix is
+    gathered."""
+    cfg = get_config("dbrx-132b")
+    d, ff, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    rows = 512 * cfg.experts_per_tok               # a 512-token row's choices
+    mesh = jax.sharding.Mesh(
+        np.asarray(v5e_host).reshape(1, 4), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
+
+    def shaped(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def ffn(xs, wg, wu, wd, sizes):
+        h = jax.nn.silu(gmm.gmm(xs, wg, sizes, impl="kernel")) \
+            * gmm.gmm(xs, wu, sizes, impl="kernel")
+        return gmm.gmm(h, wd, sizes, impl="kernel")
+
+    def worker(xs, wg, wu, wd, sizes):
+        loss = lambda *a: jnp.sum(
+            jnp.square(ffn(*a, sizes[0]).astype(jnp.float32)))
+        return jax.grad(loss, (0, 1, 2, 3))(xs, wg, wu, wd)
+
+    step = compat.shard_map(
+        worker, mesh=mesh, in_specs=(P("data"), P(), P(), P(), P("data")),
+        out_specs=(P("data"), P(), P(), P()), manual_axes=("data",))
+    experts = P("model")
+    text = jax.jit(step).lower(
+        shaped((rows, d), jnp.bfloat16, P("data")),
+        shaped((E, d, ff), jnp.bfloat16, experts),
+        shaped((E, d, ff), jnp.bfloat16, experts),
+        shaped((E, ff, d), jnp.bfloat16, experts),
+        shaped((1, E + 1), jnp.int32, P("data"))).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    local = [f"[{E // 4},{d},{ff}]", f"[{E // 4},{ff},{d}]"]
+    whole = [f"[{E},{d},{ff}]", f"[{E},{ff},{d}]"]
+    gathered = [line for line in text.splitlines()
+                if re.search(r"\sall-gather(-start)?\(", line)
+                and any(w in line.split("=")[1] for w in whole)]
+    assert not gathered, gathered
+    assert any(w in text for w in local)
