@@ -36,12 +36,14 @@ semantics and docs/wire_format.md for the payload layouts and bit accounting.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import compat
 from repro.core.efbv import EFBV, Downlink
 from repro.distributed import wire
 
@@ -205,7 +207,7 @@ def combine_global(
                 message_stacked = jax.lax.with_sharding_constraint(
                     message_stacked, NamedSharding(mesh, P()))
         d_bar_leaves = []
-        with jax.named_scope("efbv.decode"):
+        with jax.named_scope("efbv.decode"), _on_mesh(mesh):
             for payload, codec, ref in zip(message_stacked, fmt.leaves,
                                            ref_leaves):
                 # payload components carry a leading worker axis; the gather
@@ -217,6 +219,16 @@ def combine_global(
     with jax.named_scope("efbv.decode"):
         g, h_avg_new = algo.master_update(h_avg, d_bar)
     return g, h_avg_new
+
+
+def _on_mesh(mesh):
+    """``mesh`` as the ambient mesh of a decode outside shard_map: the
+    decode's Pallas kernel then runs in a shard_map over the mesh's auto
+    axes (kernels/ops.py::_unpartitioned), where GSPMD alone could not
+    partition it.  No mesh, or one already ambient, changes nothing."""
+    if mesh is None or not compat.abstract_mesh().empty:
+        return contextlib.nullcontext()
+    return jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
 
 
 def ring_allgather(message: PyTree, axis_name, n: int) -> PyTree:
@@ -258,6 +270,7 @@ def broadcast_global(
     w: PyTree,
     *,
     wire_dtype: str = "float32",
+    mesh=None,
 ) -> Tuple[PyTree, list]:
     """One downlink round: the master encodes C_s(x^{t+1} - w^t) through its
     codec and every worker applies the decoded innovation to the shared
@@ -267,8 +280,10 @@ def broadcast_global(
     :meth:`repro.core.efbv.Downlink.broadcast` through here, so the downlink
     math lives in one place.  ``key`` must be the round's
     ``downlink_key(step_key)`` so all paths draw the same broadcast.
+    A trainer's ``mesh`` is made ambient around it, as around
+    :func:`combine_global`'s decode.
     """
-    with jax.named_scope("efbv.downlink"):
+    with jax.named_scope("efbv.downlink"), _on_mesh(mesh):
         return downlink.broadcast(key, params, w, wire_dtype=wire_dtype)
 
 

@@ -1053,13 +1053,35 @@ def pack_oracle(lw: LeafWire, delta: Array) -> Tuple[Array, Array]:
     return vals, idx.astype(jnp.int32)
 
 
-def scatter_add(lw: LeafWire, vals: Array, idx: Array) -> Array:
+def scatter_add(lw: LeafWire, vals: Array, idx: Array, *,
+                kernel: Optional[str] = None) -> Array:
     """Payload -> dense flat (size,) vector.
 
     Accepts one message (nb, kb) or the worker-stacked all-gather result
     (n, nb, kb); the stacked form is scatter-SUMMED per block (the local
-    combine of the sparse_allgather collective -- divide by n for the mean).
+    combine of the sparse_allgather collective -- divide by n for the mean),
+    each position's terms in ascending worker, then slot, order.
+
+    Dispatches like :func:`fused_pack`: the sort-free Pallas kernel
+    (kernels/pack.py) on TPU; the jnp scatter elsewhere, for a block the
+    kernel refuses (``pack.unpack_gap``), and for values that are not f32,
+    which the scatter sums in their own dtype.  Kernel and scatter give the
+    same bits.
     """
+    mode = _kernel_mode(kernel)
+    if mode in ("pallas", "interpret"):
+        from repro.kernels import pack
+        gap = (f"{vals.dtype} values" if vals.dtype != jnp.float32
+               else pack.unpack_gap(lw.block, vals.size // lw.nb))
+        if gap is not None:
+            if kernel in ("pallas", "interpret"):
+                raise ValueError(f"Pallas decode kernel: {gap}")
+            mode = "oracle"
+    if mode in ("pallas", "interpret"):
+        from repro.kernels import ops
+        out = ops.block_decode_sum(vals, idx, block=lw.block,
+                                   interpret=(mode == "interpret"))
+        return out.reshape(-1)[:lw.size]
     if vals.ndim == 3:  # (n, nb, kb) -> (nb, n*kb)
         vals = jnp.moveaxis(vals, 0, 1).reshape(vals.shape[1], -1)
         idx = jnp.moveaxis(idx, 0, 1).reshape(idx.shape[1], -1)

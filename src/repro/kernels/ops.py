@@ -104,6 +104,30 @@ def efbv_pack_update(g: Array, h: Array, lam: float, block: int = 1024,
     return (vals[:nb], idx[:nb]), h_new
 
 
+# jitted where the kernel is called, inside _unpartitioned's shard_map: XLA
+# names a custom call after its innermost call, so the kernel's instruction
+# is %block_unpack_sum.N on every mesh, the name a trace finds it by
+_block_unpack_sum = jax.jit(KP.block_unpack_sum,
+                            static_argnames=("block", "interpret"))
+
+
+def block_decode_sum(vals: Array, idx: Array, block: int,
+                     interpret: bool | None = None) -> Array:
+    """Sort-free block-top-k decode-sum (kernels/pack.py): the payload
+    (values f32, block-local indices int32), (nb, kb) or worker-stacked
+    (n, nb, kb), -> the dense (nb, block) f32 sum, each position's terms
+    added in ascending worker, then slot, order.  The kernel reads the
+    payload transposed, one block per lane."""
+    interpret = _interpret_default() if interpret is None else interpret
+    if vals.ndim == 2:
+        vals, idx = vals[None], idx[None]
+    n, nb, kb = vals.shape
+    lanes = lambda a: jnp.transpose(a, (0, 2, 1)).reshape(n * kb, nb)
+    return _unpartitioned(
+        functools.partial(_block_unpack_sum, block=block,
+                          interpret=interpret), lanes(vals), lanes(idx))
+
+
 # default flat-vector slab width for the codec kernels below (rand-k / QSGD
 # have no block structure of their own; 1024 lanes = 8 full vregs)
 _CODEC_COLS = 1024

@@ -20,6 +20,11 @@ dense compressed d lives only in VMEM, never in HBM:
     (g, h, uniforms) emits the int8/int16 quantized level stream and h_out
     -- the dequantized d is built in VMEM for the h update and discarded.
 
+The decode side has one kernel: the block-top-k decode-sum
+(`_block_unpack_sum_kernel`) rebuilds each dense block from its own
+(value, index) pairs by compares against a position iota -- no sort, no
+scatter.
+
 All kernels reproduce the jnp oracles' f32 arithmetic op-for-op, which is
 what makes the payloads bit-identical across oracle / interpret / compiled
 backends -- the differential harness in tests/harness.py pins this.
@@ -196,11 +201,17 @@ def pack_tile(nb: int, block: int, kb: int) -> int:
     default.  The last grid step may be ragged: rows are independent lanes
     of the transposed tile, so out-of-bounds rows touch no kept entry, and
     their outputs are dropped."""
+    return widest_tile(nb, lambda t: pack_vmem_bytes(t, block, kb))
+
+
+def widest_tile(nb: int, vmem_bytes) -> int:
+    """``nb`` where it is at most 128 (a block equal to the full array
+    dimension), else the widest of ``PACK_TILES`` that ``nb`` fills and
+    whose ``vmem_bytes(tile)`` fits ``SCOPED_VMEM``, else the narrowest."""
     if nb <= 128:
         return nb
     for tile in PACK_TILES:
-        if (tile <= nb
-                and pack_vmem_bytes(tile, block, kb) <= SCOPED_VMEM):
+        if tile <= nb and vmem_bytes(tile) <= SCOPED_VMEM:
             return tile
     return PACK_TILES[-1]
 
@@ -367,3 +378,98 @@ def qsgd_pack_update_pallas(g2d: Array, h2d: Array, u2d: Array, norm: Array,
                    _out((nr, cols), h2d.dtype, norm, g2d, h2d, u2d)),
         interpret=interpret,
     )(norm, g2d, h2d, u2d)
+
+
+# ---------------------------------------------------------------------------
+# block-top-k decode-sum: the dense sum of (worker-stacked) payloads
+# ---------------------------------------------------------------------------
+#
+# The block-top-k payload's indices are local to their block, so every dense
+# block is rebuilt from its own ``n * kb`` (value, index) pairs alone: no
+# global sort of the pairs and no serialized scatter, which is what XLA's TPU
+# lowering of ``zeros.at[rows, idx].add(vals)`` costs.
+#
+# The kernel reads the payload transposed, ``(n * kb, nb)`` with one block
+# per lane, and keeps a ``(block, rows)`` accumulator with one position per
+# sublane: each term is a compare of its index row against an int32 iota of
+# the positions, a select of its value row and an add, so a pair broadcasts
+# along sublanes and never crosses lanes.  The tile is transposed in VMEM to
+# the lane-dense ``(rows, block)`` slab it writes.  On a TPU v5e these
+# whole-tile terms ran 1.2-3x faster than an explicit (128, 128) chunking
+# that holds the accumulator in vregs.
+#
+# Terms go in ascending worker order, then ascending slot order: the order
+# the CPU scatter adds them in, so the sums are bitwise equal to the jnp
+# oracle's (``wire.scatter_add``).  Terms that miss a position add ``+0.0``,
+# which leaves every sum but ``-0.0`` unchanged, and the accumulator starts
+# at ``+0.0`` as the scatter's zeros do, so it never holds ``-0.0``.
+
+# VMEM bytes per element of a tile: the (rows, block) output
+# double-buffered, and about five (block, rows) 4-byte intermediates (the
+# accumulator, the position iota, a term's mask and select, the transpose)
+_UNPACK_ROW_BYTES = 2 * 4 + 5 * 4
+# VMEM bytes per term of a tile row: the f32 value and int32 index payload
+# blocks, double-buffered
+_UNPACK_TERM_BYTES = 2 * 2 * 4
+
+
+def unpack_vmem_bytes(tile: int, block: int, terms: int) -> int:
+    """Upper estimate of the VMEM one grid step holds, for ``tile`` blocks
+    of ``block`` positions rebuilt from ``terms`` pairs each."""
+    return tile * (block * _UNPACK_ROW_BYTES + terms * _UNPACK_TERM_BYTES)
+
+
+def unpack_gap(block: int, terms: int) -> Optional[str]:
+    """Why the kernel cannot take ``block``/``terms``, or None: the block
+    must fill 128 lanes, and the narrowest legal tile (128 blocks) must fit
+    ``PACK_VMEM_MAX``."""
+    if block % 128:
+        return f"block {block} is not a multiple of 128"
+    need = unpack_vmem_bytes(STREAM_TILE_NB, block, terms)
+    if need > PACK_VMEM_MAX:
+        return (f"needs {need / 2 ** 20:.0f} MiB of VMEM for block {block}, "
+                f"{terms} terms at {STREAM_TILE_NB} blocks, over its "
+                f"{PACK_VMEM_MAX // 2 ** 20} MiB")
+    return None
+
+
+def _block_unpack_sum_kernel(vals_ref, idx_ref, out_ref):
+    """One tile: vals/idx (terms, rows) -> out (rows, block), summed.  The
+    terms are python-unrolled: on a TPU v5e a loop over the workers ran
+    a four-worker sum 2.6-3x slower, for a third of the compile time."""
+    terms, rows = vals_ref.shape
+    block = out_ref.shape[1]
+    pos = jax.lax.broadcasted_iota(jnp.int32, (block, rows), 0)
+    acc = jnp.zeros((block, rows), jnp.float32)
+    for j in range(terms):  # worker-major, then slot
+        hit = pos == idx_ref[pl.ds(j, 1), :]
+        acc = acc + jnp.where(hit, vals_ref[pl.ds(j, 1), :], 0.0)
+    out_ref[...] = acc.T
+
+
+def block_unpack_sum(vals_t: Array, idx_t: Array, block: int, *,
+                     interpret: bool = False) -> Array:
+    """vals_t f32 / idx_t int32: the transposed payload ``(n * kb, nb)``,
+    term ``w * kb + s`` being worker w's slot s.  Returns the dense
+    ``(nb, block)`` f32 sum.  Raises ValueError where ``unpack_gap`` names
+    a gap.  ``ops.block_decode_sum`` jits it under this name, which XLA
+    gives the kernel's instruction (``%block_unpack_sum.N``)."""
+    terms, nb = vals_t.shape
+    assert idx_t.shape == (terms, nb), (idx_t.shape, vals_t.shape)
+    gap = unpack_gap(block, terms)
+    if gap is not None:
+        raise ValueError(gap)
+    # pack_tile's rule under this kernel's VMEM estimate; a ragged last step
+    # is safe, blocks being independent lanes and the rows past nb dropped
+    tile = widest_tile(nb, lambda t: unpack_vmem_bytes(t, block, terms))
+    payload = pl.BlockSpec((terms, tile), lambda i: (0, i))
+    vmem_limit = max(SCOPED_VMEM, unpack_vmem_bytes(tile, block, terms))
+    return pl.pallas_call(
+        _block_unpack_sum_kernel,
+        grid=(pl.cdiv(nb, tile),),
+        in_specs=[payload, payload],
+        out_specs=pl.BlockSpec((tile, block), lambda i: (i, 0)),
+        out_shape=_out((nb, block), jnp.float32, vals_t, idx_t),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+    )(vals_t.astype(jnp.float32), idx_t.astype(jnp.int32))

@@ -341,7 +341,7 @@ def make_train_step(
             # replicated copy of w suffices (and absent workers under
             # partial participation decode the identical payload).
             w, _ = broadcast_global(downlink, downlink_key(key), params, w,
-                                    wire_dtype=wire_dtype)
+                                    wire_dtype=wire_dtype, mesh=mesh)
             with jax.named_scope("efbv.step_metrics"):
                 metrics["w_err"] = global_norm(
                     jax.tree.map(lambda a, b: a - b, params, w))
@@ -506,7 +506,7 @@ def make_train_step_fsdp(
         w = state.w
         if downlink is not None:
             w, _ = broadcast_global(downlink, downlink_key(key), params, w,
-                                    wire_dtype=wire_dtype)
+                                    wire_dtype=wire_dtype, mesh=mesh)
             with jax.named_scope("efbv.step_metrics"):
                 metrics["w_err"] = global_norm(
                     jax.tree.map(lambda a, b: a - b, params, w))

@@ -5,14 +5,16 @@ described, not attached, so these run on a CPU-only host.  They catch what
 interpret mode cannot: Mosaic refusing an op (a float iota, a DMA narrower
 than the 128-lane tiling), a kernel over its VMEM or SMEM budget.  Shapes
 are qwen2-0.5b's (configs/qwen2_0_5b.py): the stacked MLP leaf of
-24 x 896 x 4864 f32 elements (block-top-k also at the embedding and the
-final norm, and at blocks 1024 and 4096), and for rand-k (whose kernel compares f32
-positions below 2**24) the stacked k/v projection of 24 x 896 x 128.  The
-MoE layer's grouped products (kernels/gmm.py) compile at
-granite-moe-3b-a800m's widths (d 1536, experts of 512, 8 held) on one
-4,096-token row's 32,768 assignments, and at dbrx-132b's (d 6144, 16
-experts of 10,752, top-4) on the described host's four chips as a
-4-way model axis, where the experts are expert-parallel.
+24 x 896 x 4864 f32 elements (block-top-k's pack and decode-sum also at
+the embedding, the pack at the final norm, and both at block 4096, the pack
+at block 1024 too), and for rand-k (whose kernel compares f32 positions
+below 2**24) the stacked k/v projection of 24 x 896 x 128.  The MoE
+layer's grouped products (kernels/gmm.py) compile at granite-moe-3b-a800m's
+widths (d 1536, experts of 512, 8 held) on one 4,096-token row's 32,768
+assignments, the decode-sum at its stacked expert leaf of
+8 x 8 x 1536 x 512, and the grouped products at dbrx-132b's (d 6144, 16
+experts of 10,752, top-4) on the described host's four chips as a 4-way
+model axis, where the experts are expert-parallel.
 """
 
 from __future__ import annotations
@@ -30,7 +32,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import compat
 from repro.configs import get_config
-from repro.kernels import gmm, pack
+from repro.kernels import gmm, ops, pack
 
 MLP_LEAF = 24 * 896 * 4864
 KV_LEAF = 24 * 896 * 128
@@ -90,6 +92,27 @@ def test_block_topk_pack_compiles(one_chip, nb, block, kb, stream):
     fn = functools.partial(pack.pack_update_pallas, lam=0.9, kb=kb,
                            stream=stream)
     assert "tpu_custom_call" in _compiled_text(fn, slab, slab)
+
+
+EXPERT_LEAF = 8 * 8 * 1536 * 512  # granite's 8 layers of 8 held experts
+
+
+@pytest.mark.parametrize("n", [1, 4])
+@pytest.mark.parametrize("nb, block, kb", [
+    (MLP_LEAF // 256, 256, 16), (EMBED_BLOCKS, 256, 16),
+    (EMBED_4096_BLOCKS, 4096, 64), (EXPERT_LEAF // 256, 256, 16)],
+    ids=["mlp", "embed", "embed-b4096", "expert"])
+def test_block_unpack_sum_compiles(one_chip, nb, block, kb, n):
+    """The decode-sum kernel at qwen2's MLP leaf, its embedding (ragged at
+    the tile) at blocks 256 and 4096, and granite's expert leaf, on one
+    message and on four stacked: within the VMEM limit its tile asks for,
+    and one kernel call each."""
+    vals = jax.ShapeDtypeStruct((n, nb, kb), jnp.float32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((n, nb, kb), jnp.int32, sharding=one_chip)
+    fn = functools.partial(ops.block_decode_sum, block=block,
+                           interpret=False)
+    text = _compiled_text(fn, vals, idx)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
 def test_qsgd_pack_compiles(one_chip):
@@ -173,3 +196,44 @@ def test_grouped_products_expert_parallel_compile(v5e_host):
                 and any(w in line.split("=")[1] for w in whole)]
     assert not gathered, gathered
     assert any(w in text for w in local)
+
+
+@pytest.mark.parametrize("downlink", ["", "block_topk:256,16"],
+                         ids=["uplink", "downlink"])
+def test_sparse_decode_compiles_in_four_chip_step(v5e_host, monkeypatch,
+                                                  downlink):
+    """The EF-BV step of qwen2's small preset on the described host's four
+    chips (mesh 4x1, four workers), with the Pallas kernels: the decode-sum
+    runs outside the trainers' shard_map, where GSPMD cannot partition a
+    Mosaic kernel, once a leaf on the uplink and again on a block-top-k
+    downlink."""
+    from repro.launch import train
+    from repro.models import build_model
+
+    monkeypatch.setenv("REPRO_WIRE_KERNEL", "pallas")
+    args = train.parse_args([
+        "--arch", "qwen2-0.5b", "--smoke", "--mesh", "4x1",
+        "--global-batch", "4", "--seq", "64", "--steps", "2",
+        "--compressor", "block_topk:256,16", "--agg", "sparse_allgather",
+        "--downlink", downlink])
+    spec = train.load_spec(args)
+    run = train.build(spec)
+    mesh = jax.sharding.Mesh(
+        np.asarray(v5e_host).reshape(4, 1), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    model = build_model(train.get_smoke_config(spec.problem))
+    opt = train.make_optimizer(args, spec)
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    shapes = jax.eval_shape(lambda p: run.init_state(p, opt, mesh), params)
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, run.state_shardings(mesh, model.param_specs(), shapes))
+    rows = jax.ShapeDtypeStruct((4, 64), jnp.int32,
+                                sharding=NamedSharding(mesh, P("data")))
+    step = run.train_step(model.loss, opt, mesh)
+    text = step.lower(state, {"tokens": rows, "labels": rows},
+                      jax.eval_shape(lambda: jax.random.key(0))
+                      ).compile().as_text()
+    leaves = len(jax.tree.leaves(params))
+    decodes = re.findall(r"%block_unpack_sum(?:\.\d+)? = ", text)
+    assert len(decodes) == leaves * (2 if downlink else 1)

@@ -150,6 +150,93 @@ def test_pack_oracle_matches_compressor_encode():
 
 
 # ---------------------------------------------------------------------------
+# the decode-sum kernel is bit-identical to the scatter
+# ---------------------------------------------------------------------------
+
+def _stacked_payload(lw, n, inputs="normal"):
+    """n workers' real block-top-k messages of one leaf, stacked (n, nb,
+    kb) in the wire's value dtype."""
+    msgs = []
+    for w in range(n):
+        key = jax.random.key(100 + w)
+        if inputs == "normal":
+            x = jax.random.normal(key, (lw.size,))
+        else:  # the tied inputs: kept -0.0 and +0.0 in every other block
+            sign = jnp.where(jax.random.bernoulli(key, 0.5, (lw.size,)),
+                             1.0, -1.0)
+            x = sign * jnp.where((jnp.arange(lw.size) // lw.block) % 2, 0.0,
+                                 0.5)
+        msgs.append(lw.encode(None, x))
+    return tuple(jnp.stack(a) for a in zip(*msgs))
+
+
+def _unpack_case(block, kb, n, via="scatter", inputs="normal",
+                 val_dtype="float32"):
+    # 130 blocks: a ragged last grid step past a 128-block tile, and a
+    # padded tail of 37 values
+    return pytest.param(130 * block - 37, block, kb, n, via, inputs,
+                        val_dtype,
+                        id=f"b{block}-kb{kb}-n{n}-{via}-{inputs}-{val_dtype}")
+
+
+UNPACK_CASES = [_unpack_case(b, kb, n) for b in (256, 1024, 4096)
+                for kb in (16, 64) for n in (1, 4)] + [
+    _unpack_case(256, 16, 4, inputs="tied"),
+    _unpack_case(256, 16, 4, via="chunks2"),
+    _unpack_case(256, 16, 4, via="decode", val_dtype="bfloat16"),
+    _unpack_case(256, 16, 1, via="decode", val_dtype="bfloat16"),
+]
+
+
+@pytest.mark.parametrize("d,block,kb,n,via,inputs,val_dtype", UNPACK_CASES)
+def test_block_unpack_sum_matches_scatter(monkeypatch, d, block, kb, n, via,
+                                          inputs, val_dtype):
+    """The sort-free decode-sum kernel (interpret mode) against the jnp
+    scatter, bit for bit: one message (n = 1) and four stacked, blocks of
+    256 to 4096, through ``scatter_add`` itself, the pipelined exchange's
+    two-chunk decode and a bf16 wire's decode, whose values the codec
+    widens to f32 before the sum."""
+    lw = wire.LeafWire(shape=(d,), size=d, block=block, kb=kb,
+                       val_dtype=val_dtype)
+    vals, idx = _stacked_payload(lw, n, inputs)
+    if n == 1:
+        vals, idx = vals[0], idx[0]
+    if via == "scatter":
+        ref = wire.scatter_add(lw, vals, idx, kernel="oracle")
+        got = wire.scatter_add(lw, vals, idx, kernel="interpret")
+    else:
+        def decode():
+            if via == "chunks2":
+                return wire.chunked_decode_sum(lw, (vals, idx), 2)
+            return lw.decode_sum((vals, idx))
+
+        monkeypatch.setenv("REPRO_WIRE_KERNEL", "oracle")
+        ref = decode()
+        monkeypatch.setenv("REPRO_WIRE_KERNEL", "interpret")
+        got = decode()
+    assert_bit_identical(got, ref, f"{(d, block, kb, n, via)}")
+    assert_bit_identical(_float_bits(got), _float_bits(ref), via)
+
+
+@pytest.mark.parametrize("block, dtype, gap", [
+    (100, jnp.float32, "block 100 is not a multiple of 128"),
+    (256, jnp.bfloat16, "bfloat16 values"),
+    (2 ** 17, jnp.float32, "MiB of VMEM"),
+], ids=["block", "bf16", "vmem"])
+def test_block_unpack_sum_gaps_fall_back_to_scatter(block, dtype, gap):
+    """A payload the decode kernel refuses takes the jnp scatter under
+    'auto' (and on a TPU); an explicit kernel request names the gap."""
+    lw = wire.LeafWire(shape=(3 * block,), size=3 * block, block=block,
+                       kb=4)
+    vals, idx = _stacked_payload(lw, 2)
+    vals = vals.astype(dtype)
+    ref = wire.scatter_add(lw, vals, idx, kernel="oracle")
+    assert_bit_identical(wire.scatter_add(lw, vals, idx), ref, gap)
+    with pytest.raises(ValueError, match=gap):
+        wire.scatter_add(lw, vals, idx, kernel="interpret")
+
+
+# ---------------------------------------------------------------------------
 # whole-trajectory bit-identity (the acceptance criterion)
 # ---------------------------------------------------------------------------
 
