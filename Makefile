@@ -91,7 +91,7 @@ smoke-tree:
 	    --global-batch 8 --seq 32
 
 # staged fine-tuning harness: the committed MoE spec (smallest MoE config,
-# expert-sparse per-leaf wire, fsdp backend) through all four stages, with
+# expert-sparse per-leaf wire, shard_map backend) through all four stages, with
 # the multi-host-shaped mesh simulated at 2 processes (docs/finetuning.md)
 smoke-finetune:
 	JAX_PLATFORMS=cpu $(PY) -m repro.launch.finetune \

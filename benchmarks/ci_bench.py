@@ -8,7 +8,7 @@ Two files, two kinds of signal:
 
 * BENCH_perf.json -- measured on this host (noisy across machines, a
   trajectory within one runner class): steps/sec + compile time of the
-  pinned smoke train-step (benchmarks/perf_iter.py::SMOKE), us/call of the
+  pinned smoke train-step (SMOKE below), us/call of the
   fused-vs-unfused wire pack, and HLO byte counts (compiled train step +
   AOT TPU exports of the three fused kernels) as a code-size trajectory.
 
@@ -23,9 +23,9 @@ Two files, two kinds of signal:
   BENCH_perf.json `serve_fleet` row carries the measured fleet tok/s and
   hot-swap latency for the same spec.  The `zoo_scaling` table (both files;
   benchmarks/zoo_scaling.py) carries the model-scale rows: every committed
-  fine-tune spec (examples/specs/finetune_moe.json + zoo_*_fsdp.json, >=3
-  model families incl. MoE and mamba2) measured under its compressed FSDP
-  wire -- exact up+down bits per round in BENCH_bits.json (with the MoE
+  fine-tune spec (examples/specs/finetune_moe.json + zoo_qwen2.json +
+  zoo_mamba2.json, >=3 model families incl. MoE and mamba2) measured under
+  its compressed wire -- exact up+down bits per round in BENCH_bits.json (with the MoE
   expert-sparsity gate: expert-leaf uplink <= 0.5x the dense block-top-k
   budget) and steps/sec through the staged fine-tune harness in
   BENCH_perf.json.
@@ -50,6 +50,7 @@ if "XLA_FLAGS" not in os.environ:
 import argparse      # noqa: E402
 import json          # noqa: E402
 import platform      # noqa: E402
+import time          # noqa: E402
 
 
 D_BITS = 1 << 16  # codec accounting vector size (matches compressor_bench)
@@ -239,10 +240,112 @@ def bits_payload():
     }
 
 
+# the pinned CI/`make bench` configuration -- change it and every later
+# BENCH_perf.json entry starts a new trajectory, so don't
+SMOKE = dict(arch="qwen2-0.5b", mesh=(2, 2), steps=4, global_batch=8, seq=32,
+             compressor="block_topk:256,16", agg="sparse_allgather",
+             downlink="qsgd:16")
+
+
+def smoke_rows(pipeline: str = "off", leaf_codecs: str = ""):
+    """Measure the pinned smoke train-step (see SMOKE): steps/sec excluding
+    compile and warmup, compile seconds, and compiled-HLO bytes.  Needs >= 4
+    XLA host devices (XLA_FLAGS at the top of this module).
+
+    ``pipeline`` ('off' | 'depth:1') selects the execution schedule; the
+    depth:1 row lands in BENCH_perf.json next to the sequential baseline
+    under its own spec fingerprint.  ``leaf_codecs`` (per-leaf codec rules,
+    docs/wire_format.md) switches the wire to the pytree-native TreeWire --
+    the smoke_train_step_tree row; '' keeps the flat wire and the existing
+    rows byte-compatible."""
+    import jax
+    import numpy as np
+
+    from repro.configs import get_smoke_config
+    from repro.core import Downlink, EFBV, make_compressor
+    from repro.core.efbv import Pipeline
+    from repro.data import SyntheticLM, make_batch_shardings
+    from repro.distributed import wire
+    from repro.launch.mesh import make_mesh, num_workers
+    from repro.models import build_model
+    from repro.optim import adamw, cosine
+    from repro.train import (init_train_state, make_train_step,
+                             train_state_shardings)
+
+    cfg = get_smoke_config(SMOKE["arch"])
+    mesh = make_mesh(SMOKE["mesh"])
+    n = num_workers(mesh)
+    model = build_model(cfg)
+    comp = make_compressor(SMOKE["compressor"])
+    pipe = Pipeline.parse(pipeline)
+    rules = wire.parse_leaf_rules(leaf_codecs) if leaf_codecs else None
+    algo = EFBV.make(comp, d=max(cfg.d_model * max(cfg.d_ff, 1), 1), n=n,
+                     pipeline=pipe.depth or None, leaf_rules=rules)
+    downlink = Downlink.parse(SMOKE["downlink"])
+    opt = adamw(cosine(3e-4, total_steps=SMOKE["steps"], warmup_steps=1))
+
+    params = model.init(jax.random.key(0))
+    state = init_train_state(params, opt, mesh, bidirectional=True,
+                             algo=algo, agg_mode=SMOKE["agg"], pipeline=pipe)
+    sh = train_state_shardings(mesh, model.param_specs(), state)
+    state = jax.tree.map(lambda x, s: jax.device_put(x, s), state, sh)
+    data = SyntheticLM(vocab=cfg.vocab, seq_len=SMOKE["seq"],
+                       global_batch=SMOKE["global_batch"], n_workers=n,
+                       seed=0)
+    step_fn = make_train_step(model.loss, opt, algo, mesh,
+                              agg_mode=SMOKE["agg"], downlink=downlink,
+                              pipeline=pipe)
+
+    key = jax.random.key(0)
+    batch = make_batch_shardings(mesh, data.batch(0))
+    t0 = time.perf_counter()
+    compiled = step_fn.lower(state, batch, key).compile()
+    compile_s = time.perf_counter() - t0
+    hlo_bytes = len(compiled.as_text().encode())
+
+    # drive the AOT-compiled executable directly (calling step_fn again
+    # would recompile through jit's separate dispatch cache): one warmup
+    # dispatch, then the timed steps.  GSPMD may emit a few output leaves
+    # with different shardings than the input layout the step was compiled
+    # for (e.g. a small norm param flipping to 'model'), and AOT calls are
+    # strict about input shardings -- reshard those leaves back outside the
+    # timed region.
+    resync = lambda st: jax.tree.map(
+        lambda x, s: x if x.sharding == s else jax.device_put(x, s), st, sh)
+    state, warm_metrics = compiled(state, batch, key)
+    # warmup synchronizes on EVERYTHING it produced, so no async dispatch
+    # (or lazy host transfer) bleeds into the first timed step
+    jax.block_until_ready((state, warm_metrics))
+    times = []
+    for i in range(SMOKE["steps"]):
+        state = resync(state)
+        batch = make_batch_shardings(mesh, data.batch(i + 1))
+        t0 = time.perf_counter()
+        state, metrics = compiled(state, batch, jax.random.fold_in(key, i))
+        # the step isn't done until every output leaf is: blocking only on
+        # params used to stop the clock while h / h_avg / w / the in-flight
+        # payload / metrics could still be computing
+        jax.block_until_ready((state, metrics))
+        times.append(time.perf_counter() - t0)
+    sec_per_step = float(np.median(times))
+    return {
+        "config": {**{k: (list(v) if isinstance(v, tuple) else v)
+                      for k, v in SMOKE.items()}, "pipeline": pipeline,
+                   # only a real rule set enters the row (the flat rows stay
+                   # byte-compatible with the pre-field trajectory)
+                   **({"leaf_codecs": leaf_codecs} if leaf_codecs else {})},
+        "steps_per_sec": round(1.0 / sec_per_step, 4),
+        "sec_per_step_median": round(sec_per_step, 4),
+        "compile_s": round(compile_s, 2),
+        "train_step_hlo_bytes": hlo_bytes,
+        "final_loss": round(float(metrics["loss"]), 4),
+    }
+
+
 def perf_payload(fast: bool = True):
     import jax
 
-    from benchmarks import compressor_bench, perf_iter
+    from benchmarks import compressor_bench
 
     # key each smoke row by the ACTUAL train-step experiment it measures
     # (same identity scheme as the BENCH_bits.json rows); worker count and
@@ -253,7 +356,7 @@ def perf_payload(fast: bool = True):
     from repro.core.spec import mesh_worker_count
     from repro.launch.train import tuning_dim
 
-    s = perf_iter.SMOKE
+    s = SMOKE
 
     def smoke_fingerprint(pipeline: str = "off",
                           leaf_codecs: str = "") -> str:
@@ -265,19 +368,19 @@ def perf_payload(fast: bool = True):
             d=tuning_dim(get_smoke_config(s["arch"])), steps=s["steps"],
             seed=0, pipeline=pipeline, leaf_codecs=leaf_codecs).fingerprint()
 
-    smoke = perf_iter.smoke_rows()
+    smoke = smoke_rows()
     # the pipelined smoke row + the perf gate: the depth-1 schedule only
     # removes a data dependence, so its steps/sec must never lose to the
     # sequential row measured in the SAME run.  Both sides re-measure on a
     # losing attempt -- a transiently loaded host slows whichever row it
     # happens to overlap, and one fresh pair beats comparing a noisy row
     # against a stale one.
-    smoke_pipe = perf_iter.smoke_rows("depth:1")
+    smoke_pipe = smoke_rows("depth:1")
     for _ in range(2):
         if smoke_pipe["steps_per_sec"] >= smoke["steps_per_sec"]:
             break
-        smoke = perf_iter.smoke_rows()
-        smoke_pipe = perf_iter.smoke_rows("depth:1")
+        smoke = smoke_rows()
+        smoke_pipe = smoke_rows("depth:1")
     assert smoke_pipe["steps_per_sec"] >= smoke["steps_per_sec"], (
         f"pipelined smoke regressed below the sequential baseline: "
         f"{smoke_pipe['steps_per_sec']} < {smoke['steps_per_sec']} steps/s")
@@ -293,12 +396,12 @@ def perf_payload(fast: bool = True):
     # sequential/pipelined pair exactly as gated.
     tree_leaf_codecs = "*embed*=qsgd:16;*norm*=identity"
     flat_ref = smoke
-    smoke_tree = perf_iter.smoke_rows(leaf_codecs=tree_leaf_codecs)
+    smoke_tree = smoke_rows(leaf_codecs=tree_leaf_codecs)
     for _ in range(2):
         if smoke_tree["steps_per_sec"] >= flat_ref["steps_per_sec"]:
             break
-        flat_ref = perf_iter.smoke_rows()
-        smoke_tree = perf_iter.smoke_rows(leaf_codecs=tree_leaf_codecs)
+        flat_ref = smoke_rows()
+        smoke_tree = smoke_rows(leaf_codecs=tree_leaf_codecs)
     assert smoke_tree["steps_per_sec"] >= flat_ref["steps_per_sec"], (
         f"per-leaf tree wire regressed below the flat-wire baseline: "
         f"{smoke_tree['steps_per_sec']} < {flat_ref['steps_per_sec']} "
@@ -327,7 +430,7 @@ def perf_payload(fast: bool = True):
     }
 
     # the model-zoo scaling rows: steps/sec of every committed fine-tune
-    # spec through the staged harness under its compressed FSDP wire
+    # spec through the staged harness under its compressed wire
     # (benchmarks/zoo_scaling.py), keyed by the committed fingerprints --
     # the model-scale leg of the bench trajectory
     from benchmarks import zoo_scaling

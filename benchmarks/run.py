@@ -11,7 +11,6 @@ One section per paper table/figure:
                (benchmarks/zoo_scaling.py; the zoo model-scale rows run in
                benchmarks/ci_bench.py)
   compressor-- compression micro-benchmarks incl. the Pallas kernel
-  roofline  -- per-(arch x shape) roofline terms from the dry-run artifacts
 """
 
 from __future__ import annotations
@@ -27,13 +26,13 @@ def main() -> None:
                     help="paper-scale runs (slower); default is fast mode")
     ap.add_argument("--only", default="",
                     help="comma list of sections (tab3,fig2,fig3,n_scaling,"
-                         "compressor,roofline)")
+                         "compressor)")
     args = ap.parse_args()
     fast = not args.full
     only = set(args.only.split(",")) if args.only else None
 
     from benchmarks import (compressor_bench, paper_fig2, paper_fig3,
-                            paper_tab3, roofline, zoo_scaling)
+                            paper_tab3, zoo_scaling)
     from benchmarks.common import emit
 
     sections = [
@@ -42,7 +41,6 @@ def main() -> None:
         ("fig2", lambda: paper_fig2.run(fast)[0]),
         ("fig3", lambda: paper_fig3.run_bench(fast)),
         ("n_scaling", lambda: zoo_scaling.run_bench(fast)),
-        ("roofline", lambda: roofline.run(fast)),
     ]
     print("name,us_per_call,derived")
     for name, fn in sections:

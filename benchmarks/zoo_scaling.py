@@ -13,9 +13,9 @@ stays continuous):
   the measured suboptimality degrade gracefully.
 
 * **Model-zoo scaling** (:func:`zoo_rows`): the committed fine-tune specs
-  (examples/specs/finetune_moe.json + zoo_*_fsdp.json -- smoke-scaled
-  stand-ins for each model family) run through the staged harness
-  (repro/train/loop.py) under the compressed FSDP wire, recording measured
+  (examples/specs/finetune_moe.json + zoo_qwen2.json + zoo_mamba2.json --
+  smoke-scaled stand-ins for each model family) run through the staged
+  harness (repro/train/loop.py) under the compressed wire, recording measured
   steps/sec and exact uplink+downlink bits per round, keyed by each spec's
   committed fingerprint.  These are the model-scale rows of
   BENCH_perf.json / BENCH_bits.json (benchmarks/ci_bench.py)."""
@@ -38,8 +38,7 @@ SPECS_DIR = os.path.join(os.path.dirname(__file__), os.pardir,
 
 # the committed model-zoo fine-tune specs, one per family stand-in (moe,
 # dense, ssm); zoo_rows runs each through the staged harness
-ZOO_SPEC_FILES = ["finetune_moe.json", "zoo_qwen2_fsdp.json",
-                  "zoo_mamba2_fsdp.json"]
+ZOO_SPEC_FILES = ["finetune_moe.json", "zoo_qwen2.json", "zoo_mamba2.json"]
 
 
 def run_bench(fast: bool = True):
@@ -253,7 +252,7 @@ def zoo_bits_rows():
 def zoo_perf_rows(measure_steps: int = 3):
     """The measured half of the zoo sweep: steps/sec of every committed
     fine-tune spec through the staged harness (repro/train/loop.py) under
-    its compressed FSDP wire, keyed by the committed fingerprints.  Compile
+    its compressed wire, keyed by the committed fingerprints.  Compile
     excluded: one warm-up step, then ``measure_steps`` timed."""
     from repro.train.loop import FinetuneLoop, FinetuneSettings
 

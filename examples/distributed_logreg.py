@@ -6,7 +6,7 @@ accounting included.
 
 The whole cross-product -- compressor, algorithm mode, backend, mesh --
 lives in ONE :class:`repro.core.ExperimentSpec`; ``build(spec)`` hands back
-the trainer (``run.train_step`` dispatches shard_map vs FSDP), the state
+the shard_map trainer (``run.train_step``), the state
 init/shardings, and the exact wire accounting.
 
     PYTHONPATH=src python examples/distributed_logreg.py

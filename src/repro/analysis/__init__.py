@@ -5,8 +5,7 @@ Layers (see docs/static_analysis.md for the rule catalog):
   * :mod:`repro.analysis.framework` -- rule registry, ``# repro: noqa``
     suppressions, golden-count pinning, the runner;
   * :mod:`repro.analysis.rules`     -- the six repo-invariant AST rules;
-  * :mod:`repro.analysis.hlo`       -- HLO cost model + roofline (absorbed
-    from repro.launch) and the ``dense_free`` pack-kernel proofs;
+  * :mod:`repro.analysis.hlo`       -- the ``dense_free`` pack-kernel proofs;
   * :mod:`repro.analysis.docs`      -- markdown link check + doctest census;
   * :mod:`repro.analysis.sanitize`  -- the ``--sanitize`` runtime mode.
 

@@ -14,8 +14,8 @@ half.  Enabling sanitize mode before any jax work:
   * exports ``REPRO_SANITIZE=1`` so subprocesses (the spec-file drivers
     spawn workers) inherit the mode.
 
-Both trainers expose this as ``--sanitize``; ``make sanitize-smoke`` runs
-a smoke step of each under it.
+Both drivers (launch/train.py, launch/finetune.py) expose this as
+``--sanitize``; ``make sanitize-smoke`` runs a smoke step of each under it.
 """
 
 from __future__ import annotations

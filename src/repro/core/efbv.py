@@ -42,7 +42,7 @@ Array = jax.Array
 PyTree = Any
 
 #: fold_in tag for the per-round participation-mask key.  All execution paths
-#: (the reference driver, shard_map trainer, FSDP trainer, the differential
+#: (the reference driver, the shard_map trainer, the differential
 #: harness) derive the mask from fold_in(round_key, PARTICIPATION_FOLD) so the
 #: sampled subset S_t is identical everywhere; worker compressor keys are
 #: untouched, which is what keeps p = 1 bit-identical to full participation.
@@ -136,7 +136,7 @@ def participation_key(round_key: Array) -> Array:
 
 def downlink_key(round_key: Array) -> Array:
     """The shared derivation of the broadcast key from a round key.  All
-    execution paths (the reference driver, both trainers, the differential
+    execution paths (the reference driver, the trainer, the differential
     harness) use this, so the master's compressor draw -- and therefore the
     broadcast every worker decodes -- is identical everywhere."""
     return jax.random.fold_in(round_key, DOWNLINK_FOLD)

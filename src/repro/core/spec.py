@@ -11,7 +11,7 @@ execution cross-product --
     per-round client sampling,
     algorithm parametrization (efbv / ef21 / diana / none),
     problem (built-in convex problems or a model arch),
-    backend (reference / shard_map / fsdp),
+    backend (reference / shard_map),
     steps / seed / stepsize
 
 -- with lossless JSON round-trips, CLI-style parsing, and a stable
@@ -21,8 +21,8 @@ refuse mismatched resumes and by the CI bench to key its trajectory rows).
 :func:`build` turns a spec into a :class:`Run`: the single entry point
 whose ``.reference()`` drives :func:`repro.core.efbv.run_reference` (the
 one lax.scan driver; the historical run / run_federated / run_bidirectional
-entry points are gone), whose ``.train_step()`` dispatches
-the shard_map vs FSDP trainers, whose ``.round_bits()`` delegates to the
+entry points are gone), whose ``.train_step()`` builds
+the shard_map trainer's step, whose ``.round_bits()`` delegates to the
 exact wire accounting, and whose ``.tuned`` delegates to the paper's
 auto-tuning (:func:`repro.core.theory.tune_for`).  Every future scenario is
 a new spec field, not a fourth driver; the migration table from the old
@@ -43,7 +43,7 @@ SPEC_VERSION = 1
 
 MODES = ("efbv", "ef21", "diana", "none")
 AGG_MODES = ("dense_psum", "sparse_allgather")
-BACKENDS = ("reference", "shard_map", "fsdp")
+BACKENDS = ("reference", "shard_map")
 WIRE_DTYPES = ("float32", "bfloat16", "float16")  # == wire.VAL_DTYPES
 #: problems the reference backend can build itself (anything else is a model
 #: arch id from repro.configs.ARCHS, trainer backends only)
@@ -169,7 +169,7 @@ class ExperimentSpec:
                    single-codec wire.  (lam, nu) are tuned for the
                    worst-case leaf composition (theory.tune_tree).
     backend:       'reference' (vmap-over-workers exact semantics) |
-                   'shard_map' | 'fsdp' (the distributed trainers).
+                   'shard_map' (the distributed trainer).
     problem:       'quadratic' | 'logreg' (built-in convex problems, the
                    reference backend) or a model arch id (trainers).
     smoke:         trainer arch problems only: run the reduced (CPU-sized)
@@ -289,12 +289,12 @@ class ExperimentSpec:
                     "the pipelined schedule double-buffers the trainer's "
                     "wire payload; the reference backend runs the exact "
                     "sequential recursion (set pipeline='off', or "
-                    "backend='shard_map' / 'fsdp')")
+                    "backend='shard_map')")
             if self.problem not in REFERENCE_PROBLEMS:
                 raise SpecError(
                     f"the reference backend runs the built-in problems "
                     f"{REFERENCE_PROBLEMS}, got {self.problem!r}; model "
-                    "archs need backend='shard_map' or 'fsdp'")
+                    "archs need backend='shard_map'")
             if self.mesh:
                 raise SpecError("spec.mesh is a trainer-backend field; the "
                                 "reference backend takes n directly (set "
@@ -544,9 +544,8 @@ class Run:
 
     Exposes the spec's derived objects (``algo``, ``participation``,
     ``downlink``), the reference driver (:meth:`reference`), the
-    distributed trainers (:meth:`train_step`, dispatching shard_map vs
-    FSDP), the exact wire accounting (:meth:`round_bits`) and the paper's
-    auto-tuning (:attr:`tuned`)."""
+    distributed trainer (:meth:`train_step`), the exact wire accounting
+    (:meth:`round_bits`) and the paper's auto-tuning (:attr:`tuned`)."""
 
     def __init__(self, spec: ExperimentSpec):
         from repro.core.compressors import Identity, make_compressor
@@ -711,7 +710,7 @@ class Run:
             downlink=self.downlink, prox=prox or efbv.prox_zero,
             record=record, wire_dtype=spec.wire_dtype)
 
-    # ---- the distributed trainers ------------------------------------------
+    # ---- the distributed trainer -------------------------------------------
 
     def make_mesh(self):
         """The spec's device mesh (trainer backends).  The process must
@@ -726,23 +725,20 @@ class Run:
 
     def train_step(self, loss_fn: Callable, optimizer, mesh=None,
                    **kw) -> Callable:
-        """The jitted distributed train step of this spec, dispatching the
-        shard_map vs FSDP trainer from ``spec.backend`` and threading
+        """The jitted distributed train step of this spec, threading
         agg/wire_dtype/downlink/participation from the spec."""
-        from repro.train import make_train_step, make_train_step_fsdp
+        from repro.train import make_train_step
 
         if self.spec.backend == "reference":
             raise SpecError("backend='reference' has no distributed trainer:"
-                            " use .reference(), or set backend='shard_map' "
-                            "or 'fsdp'")
+                            " use .reference(), or set backend='shard_map'")
         mesh = self.make_mesh() if mesh is None else mesh
-        make = (make_train_step_fsdp if self.spec.backend == "fsdp"
-                else make_train_step)
-        return make(loss_fn, optimizer, self.algo, mesh,
-                    agg_mode=self.spec.agg, wire_dtype=self.spec.wire_dtype,
-                    downlink=self.downlink,
-                    participation=self.participation,
-                    pipeline=self.pipeline, **kw)
+        return make_train_step(loss_fn, optimizer, self.algo, mesh,
+                               agg_mode=self.spec.agg,
+                               wire_dtype=self.spec.wire_dtype,
+                               downlink=self.downlink,
+                               participation=self.participation,
+                               pipeline=self.pipeline, **kw)
 
     def init_state(self, params: PyTree, optimizer, mesh):
         """TrainState for this spec (bidirectional iff a downlink is set;
@@ -756,12 +752,10 @@ class Run:
                                 pipeline=self.pipeline)
 
     def state_shardings(self, mesh, param_specs: PyTree, state):
-        """NamedShardings for the TrainState, FSDP-aware per the backend."""
-        from repro.train import fsdp_state_shardings, train_state_shardings
+        """NamedShardings for the TrainState."""
+        from repro.train import train_state_shardings
 
-        fn = (fsdp_state_shardings if self.spec.backend == "fsdp"
-              else train_state_shardings)
-        return fn(mesh, param_specs, state)
+        return train_state_shardings(mesh, param_specs, state)
 
     # ---- exact wire accounting ---------------------------------------------
 

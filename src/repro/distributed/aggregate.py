@@ -276,7 +276,7 @@ def broadcast_global(
     codec and every worker applies the decoded innovation to the shared
     reconstruction w.  Returns (w_new, payloads); the payloads are what
     crosses the wire (``downlink.format_for(params).downlink_bits_per_round()``
-    bits, exactly).  Both trainers and the reference driver call
+    bits, exactly).  The trainer and the reference driver call
     :meth:`repro.core.efbv.Downlink.broadcast` through here, so the downlink
     math lives in one place.  ``key`` must be the round's
     ``downlink_key(step_key)`` so all paths draw the same broadcast.

@@ -1,8 +1,8 @@
 """Production mesh geometry.
 
 Defined as FUNCTIONS so that importing this module never touches jax device
-state (jax locks the device count on first backend init -- see
-launch/dryrun.py which must set XLA_FLAGS before anything else).
+state (jax locks the device count on first backend init, so a caller that
+forces host devices must set XLA_FLAGS before anything else).
 """
 
 from __future__ import annotations

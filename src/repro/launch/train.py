@@ -10,7 +10,7 @@ On a real cluster the same entry point takes --arch <id> (full config) and
 
 Every algorithmic knob is ONE declarative object: the flag namespace is
 folded into a :class:`repro.core.ExperimentSpec` (:func:`spec_from_args`)
-and the whole run -- EF-BV tuning, trainer dispatch (shard_map vs FSDP),
+and the whole run -- EF-BV tuning, the shard_map train step,
 federated sampling, bidirectional downlink, wire accounting -- is built via
 ``repro.core.build(spec)``.  ``--spec path.json`` loads a serialized spec
 instead (the individual algorithmic flags are then ignored); the spec JSON
@@ -99,8 +99,6 @@ def parse_args(argv=None):
                          "t-1's message while round t's is on the wire).  "
                          "With --spec, a non-default value overrides the "
                          "spec's pipeline field")
-    ap.add_argument("--trainer", default="shard_map",
-                    choices=["shard_map", "fsdp"])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=0)
@@ -136,7 +134,7 @@ def spec_from_args(args, n: int) -> ExperimentSpec:
         downlink=args.downlink,
         participation=args.participation,
         resample=args.local_batch_resample,
-        backend="fsdp" if args.trainer == "fsdp" else "shard_map",
+        backend="shard_map",
         problem=args.arch,
         smoke=args.smoke,
         mesh=args.mesh,

@@ -4,10 +4,10 @@ six assigned families (dense, moe, ssm, hybrid, encdec-audio, vlm).
 Conventions
 -----------
 * Per-layer parameters are stacked on a leading L axis and consumed with
-  ``lax.scan`` (keeps HLO size O(1) in depth -- essential for the 78-compile
-  dry-run) with optional ``jax.checkpoint`` remat per block.
+  ``lax.scan`` (keeps HLO size O(1) in depth) with optional
+  ``jax.checkpoint`` remat per block.
 * A Model never touches the mesh: it only declares PartitionSpecs over the
-  'model' axis; the trainer / dryrun decide data/pod sharding.
+  'model' axis; the trainer decides data/pod sharding.
 * ``batch`` dicts:
     train:   {"tokens": (B,S) i32, "labels": (B,S) i32, [frontend stubs]}
     prefill: {"tokens": (B,S) i32, [frontend stubs]}
@@ -77,7 +77,7 @@ class Model:
         return params
 
     def init_abstract(self) -> PyTree:
-        """ShapeDtypeStruct params (no allocation) -- for the dry-run."""
+        """ShapeDtypeStruct params (no allocation) -- for AOT lowering."""
         return jax.eval_shape(self.init, jax.random.key(0))
 
     def param_specs(self) -> PyTree:
@@ -419,7 +419,7 @@ class Model:
 
     def prefill(self, params, batch) -> Array:
         """Inference prefill: full-sequence forward, returns last-position
-        logits (B, V).  (The prefill_32k dry-run shape lowers this.)"""
+        logits (B, V)."""
         logits, _ = self.forward(params, batch)
         return logits[:, -1]
 

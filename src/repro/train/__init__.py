@@ -1,6 +1,5 @@
 from repro.train.trainer import (  # noqa: F401
     TrainState, make_train_step, init_train_state, train_state_shardings,
-    make_train_step_fsdp, fsdp_state_shardings, fsdp_specs,
 )
 from repro.train.loop import (  # noqa: F401
     FinetuneLoop, FinetuneSettings, expert_sparse_rules, finetune,

@@ -13,9 +13,8 @@ every model family in the zoo (CPM-2-style finetune staging).
 
 What the spec buys here over the raw trainers:
 
-* **FSDP + per-leaf compressed wire** -- ``backend='fsdp'`` shards params and
-  optimizer state over the worker axes while ``leaf_codecs`` routes every
-  parameter leaf through its own uplink codec (``TreeWire`` rules,
+* **Per-leaf compressed wire** -- ``leaf_codecs`` routes every parameter
+  leaf through its own uplink codec (``TreeWire`` rules,
   docs/wire_format.md).
 * **MoE expert-gradient sparsity** -- for ``family='moe'`` archs the loop
   installs :func:`repro.models.moe.zero_inactive_expert_grads` as the

@@ -31,11 +31,10 @@ cannot stack).
 
 The declarative way to obtain a train step is
 ``repro.core.build(spec).train_step(loss_fn, opt, mesh)``: the
-:class:`repro.core.ExperimentSpec` selects this builder vs
-:func:`make_train_step_fsdp` from ``spec.backend`` and threads
-agg/wire_dtype/downlink/participation from its fields (docs/api.md).
+:class:`repro.core.ExperimentSpec` threads agg/wire_dtype/downlink/
+participation from its fields into :func:`make_train_step` (docs/api.md).
 
-Both trainers name the step's layers with ``jax.named_scope``, so that a
+The step names its layers with ``jax.named_scope``, so that a
 profile of the compiled step splits its device time by layer (each
 instruction's ``op_name`` carries the innermost scope): ``efbv.fwd_bwd``
 (value_and_grad, the f32 cast, ``grad_transform``), ``efbv.compress``
@@ -355,165 +354,6 @@ def make_train_step(
             w=w,
             inflight=message if pipelined else state.inflight,
         )
-        return new_state, metrics
-
-    return jax.jit(train_step, donate_argnums=(0,))
-
-
-# ---------------------------------------------------------------------------
-# FSDP variant (beyond-paper, §Perf): pure-GSPMD trainer where parameters and
-# optimizer state are additionally sharded over the worker axes (ZeRO-3
-# style).  Per-worker gradients come from vmap over a worker-major batch
-# reshape instead of shard_map -- XLA's partitioner then emits the FSDP
-# all-gathers per layer and keeps every state shard at 1/(data*model) size.
-# Required for dbrx-132b-class models: at 16-way TP alone the fp32 params are
-# 33 GiB/device; FSDP brings params+adam+h to ~9 GiB/device.
-# ---------------------------------------------------------------------------
-
-
-def fsdp_specs(mesh, param_specs: PyTree, shapes: PyTree) -> PyTree:
-    """Add the worker axes to the first divisible, unsharded dim of each
-    param spec (classic FSDP weight sharding on top of tensor parallelism)."""
-    w = worker_axes(mesh)
-    n = num_workers(mesh)
-
-    def one(spec, leaf):
-        parts = list(spec) + [None] * (len(leaf.shape) - len(spec))
-        for i, (p, dim) in enumerate(zip(parts, leaf.shape)):
-            if p is None and dim % n == 0 and dim > 0:
-                parts[i] = w
-                break
-        return P(*parts)
-
-    return jax.tree.map(one, param_specs, shapes,
-                        is_leaf=lambda s: isinstance(s, P))
-
-
-def fsdp_state_shardings(mesh, param_specs: PyTree, state: TrainState
-                         ) -> TrainState:
-    fspecs = fsdp_specs(mesh, param_specs, state.params)
-    p_sh = to_named_sharding(mesh, fspecs)
-
-    shape_to_spec = {}
-    for leaf, spec in zip(jax.tree.leaves(state.params),
-                          jax.tree.leaves(fspecs, is_leaf=lambda s: isinstance(s, P))):
-        shape_to_spec.setdefault(leaf.shape, spec)
-
-    def spec_for(leaf):
-        return shape_to_spec.get(leaf.shape, P())
-
-    opt_sh = jax.tree.map(lambda l: NamedSharding(mesh, spec_for(l)), state.opt_state)
-    # h has the worker axis on dim 0; inner dims keep only the 'model' sharding
-    h_sh = to_named_sharding(mesh, stack_worker_spec(mesh, param_specs))
-    havg_sh = jax.tree.map(lambda l: NamedSharding(mesh, spec_for(l)), state.h_avg)
-    rep = NamedSharding(mesh, P())
-    # the downlink control variate w shards like the params (FSDP included:
-    # it is read back densely by every worker's grad anyway)
-    w_sh = None if state.w is None \
-        else jax.tree.map(lambda _, s: s, state.w, p_sh)
-    fl_sh = _inflight_shardings(mesh, state.inflight)
-    return TrainState(params=p_sh, opt_state=opt_sh, h=h_sh, h_avg=havg_sh,
-                      step=rep, w=w_sh, inflight=fl_sh)
-
-
-def make_train_step_fsdp(
-    loss_fn: Callable[[PyTree, Any], Tuple[jax.Array, dict]],
-    optimizer: Optimizer,
-    algo: EFBV,
-    mesh,
-    *,
-    agg_mode: str = "dense_psum",
-    wire_dtype: str = "float32",
-    downlink: Optional[Downlink] = None,
-    participation: Optional[Participation] = None,
-    pipeline: Optional[Pipeline] = None,
-    grad_transform: Optional[Callable[[PyTree], PyTree]] = None,
-) -> Callable[[TrainState, Any, jax.Array], Tuple[TrainState, dict]]:
-    """Pure-GSPMD train step: vmap over the worker axis for per-worker grads,
-    FSDP-sharded params/optimizer state, same EF-BV wire as the shard_map
-    trainer (compress_local / combine_global / broadcast_global are shared,
-    incl. the federated participation masking, the compressed downlink
-    broadcast, the worker-side ``grad_transform`` hook and the pipelined
-    one-round-stale schedule -- see
-    :func:`make_train_step` for the ``pipeline`` double-buffer semantics;
-    phase 1 runs under vmap here, so the streaming kernel variant stays
-    off)."""
-    waxes = worker_axes(mesh)
-    n = num_workers(mesh)
-    federated = participation is not None and not participation.is_full
-    pipelined = pipeline is not None and pipeline.depth > 0
-    chunks = wire.pipeline_chunks(n) \
-        if (pipelined and agg_mode == "sparse_allgather") else 1
-
-    def worker_grads(params, batch, key):
-        # batch leaves: (B, ...) -> (n, B/n, ...) worker-major
-        wb = jax.tree.map(lambda a: a.reshape((n, a.shape[0] // n) + a.shape[1:]),
-                          batch)
-        wb = jax.lax.with_sharding_constraint(
-            wb, jax.tree.map(lambda _: NamedSharding(mesh, P(waxes)), wb))
-        keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(n))
-
-        def one(wbatch):
-            with jax.named_scope("efbv.fwd_bwd"):
-                (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                    params, wbatch)
-                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-                if grad_transform is not None:
-                    grads = grad_transform(grads)
-            return loss, aux, grads
-
-        loss, aux, grads = jax.vmap(one)(wb)
-        return loss, aux, grads, keys
-
-    def train_step(state: TrainState, batch, key):
-        eval_params = state.w if downlink is not None else state.params
-        loss, aux, grads, keys = worker_grads(eval_params, batch, key)
-        # pin the stacked grads to (worker, model)-sharding
-        gspec = stack_worker_spec(mesh, jax.tree.map(
-            lambda g: P(*([None] * (g.ndim - 1))), state.h_avg))
-        widx = jnp.arange(n)
-        if federated:
-            mask = participation.sample_mask(participation_key(key), n)
-            with jax.named_scope("efbv.compress"):
-                message, h_new = jax.vmap(
-                    lambda k, g, h, m, i: compress_local(
-                        algo, k, g, h, mode=agg_mode, wire_dtype=wire_dtype,
-                        mask=m, worker=i)
-                )(keys, grads, state.h, mask, widx)
-        else:
-            with jax.named_scope("efbv.compress"):
-                message, h_new = jax.vmap(
-                    lambda k, g, h, i: compress_local(
-                        algo, k, g, h, mode=agg_mode, wire_dtype=wire_dtype,
-                        worker=i)
-                )(keys, grads, state.h, widx)
-        apply_msg = state.inflight if pipelined else message
-        g, h_avg_new = combine_global(algo, apply_msg, state.h_avg,
-                                      n_workers=n, mode=agg_mode,
-                                      wire_dtype=wire_dtype, chunks=chunks,
-                                      mesh=mesh)
-        params, updates, opt_state = _optimizer_step(optimizer, g, state)
-        with jax.named_scope("efbv.step_metrics"):
-            metrics = {"loss": jnp.mean(loss), "g_norm": global_norm(g),
-                       "update_norm": global_norm(updates),
-                       "grad_norm": jnp.mean(jax.vmap(global_norm)(grads)),
-                       "h_residual": jnp.mean(jax.vmap(
-                           lambda gi, hi: global_norm(jax.tree.map(
-                               lambda a, b: a - b, gi, hi)))(grads, h_new)),
-                       **{k: jnp.mean(v) for k, v in aux.items()}}
-        if federated:
-            metrics["participants"] = jnp.sum(mask)
-        w = state.w
-        if downlink is not None:
-            w, _ = broadcast_global(downlink, downlink_key(key), params, w,
-                                    wire_dtype=wire_dtype, mesh=mesh)
-            with jax.named_scope("efbv.step_metrics"):
-                metrics["w_err"] = global_norm(
-                    jax.tree.map(lambda a, b: a - b, params, w))
-        new_state = TrainState(params=params, opt_state=opt_state, h=h_new,
-                               h_avg=h_avg_new, step=state.step + 1, w=w,
-                               inflight=message if pipelined
-                               else state.inflight)
         return new_state, metrics
 
     return jax.jit(train_step, donate_argnums=(0,))

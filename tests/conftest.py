@@ -1,6 +1,6 @@
 """Shared test helpers.
 
-NOTE (per the dry-run spec): XLA_FLAGS / device-count forcing is NEVER set
+NOTE: XLA_FLAGS / device-count forcing is NEVER set
 globally here -- single-device tests must see 1 device.  Multi-device tests
 spawn subprocesses with their own XLA_FLAGS via run_with_devices().
 """

@@ -504,6 +504,16 @@ def test_all_registered_pack_kernels_prove_dense_free():
         assert r.max_inner <= r.tile     # nothing denser than the tile
 
 
+def test_hlo_gate_cli_proves_every_registered_kernel(capsys):
+    """``python -m repro.analysis --hlo-gate`` in-process: exit 0 and one
+    PROVEN line per registered pack kernel."""
+    assert analysis_main(["--hlo-gate"]) == 0
+    lines = capsys.readouterr().out.splitlines()
+    assert all(line.endswith("-> PROVEN") for line in lines), lines
+    assert [line.split(":")[0] for line in lines] == [
+        f"dense-free {name}" for name in sorted(hlo.PACK_KERNELS)]
+
+
 def test_dense_free_catches_a_dense_implementation(monkeypatch):
     def _dense_case():
         import jax.numpy as jnp

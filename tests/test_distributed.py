@@ -265,7 +265,7 @@ def test_federated_full_participation_bit_identical_8dev():
 
 @pytest.mark.slow
 def test_mini_dryrun_lowering_16dev():
-    """dryrun-style lower+compile on a 4x4 mini-mesh with a smoke config:
+    """AOT lower+compile on a 2x2x4 mini-mesh with a smoke config:
     proves the (pod,data,model) sharding machinery end to end, cheaply."""
     out = run_with_devices("""
         import jax, jax.numpy as jnp, dataclasses

@@ -35,9 +35,9 @@ SPECS_DIR = os.path.join(REPO, "examples", "specs")
 # BENCH_perf/BENCH_bits zoo_scaling rows are addressed across the bench
 # trajectory -- a fingerprint drift silently orphans every recorded row
 ZOO_FINGERPRINTS = {
-    "finetune_moe.json": "f67bc877b3e73340",
-    "zoo_qwen2_fsdp.json": "e379cbd8a0e45487",
-    "zoo_mamba2_fsdp.json": "6a9502177435874c",
+    "finetune_moe.json": "e9fcd7308f222d20",
+    "zoo_qwen2.json": "ebfdbf39c6d3bd29",
+    "zoo_mamba2.json": "6dafe51a691e8bb8",
 }
 
 
@@ -281,25 +281,19 @@ def test_bits_by_leaf_exact_under_routing(granite):
 
 
 # ---------------------------------------------------------------------------
-# fixed-routing fine-tune step: trainers == vmap oracle
+# fixed-routing fine-tune step: trainer == vmap oracle
 # ---------------------------------------------------------------------------
 
-def _oracle_code(n_devices, mesh_shape, steps, fsdp_atol):
+def _oracle_code(mesh_shape, steps):
     """The fixed-routing step pin, parametrized by device count.
 
     The shard_map trainer is pinned TIGHT against the vmap oracle -- its
     per-worker gradients are the same single-shard computation the oracle
-    runs, so compression sees bit-equal inputs.  The fsdp trainer computes
-    grads under vmap over the worker axis; on multi-device meshes that
-    reassociates bf16 matmuls just enough to flip block-top-k ties in the
-    embed leaf, so its pin is structural (loss + expert-slab support) plus
-    a loose parameter tolerance (``fsdp_atol``); h is only compared when
-    the tolerance is tight (tie flips land whole gradient entries in h).
+    runs, so compression sees bit-equal inputs.
     """
     return f"""
         import jax, jax.numpy as jnp
         import numpy as np
-        from jax.sharding import NamedSharding
         from repro.configs import get_smoke_config
         from repro.core import ExperimentSpec, build
         from repro.data import SyntheticLM, make_batch_shardings
@@ -307,8 +301,7 @@ def _oracle_code(n_devices, mesh_shape, steps, fsdp_atol):
         from repro.launch.mesh import make_mesh
         from repro.models import build_model, moe
         from repro.optim import constant, sgd
-        from repro.train import (fsdp_state_shardings, init_train_state,
-                                 make_train_step, make_train_step_fsdp,
+        from repro.train import (init_train_state, make_train_step,
                                  train_state_shardings)
 
         spec = ExperimentSpec.from_json(
@@ -329,35 +322,26 @@ def _oracle_code(n_devices, mesh_shape, steps, fsdp_atol):
         # differently in the last bits, enough to flip block-top-k ties
         grad_fn = jax.jit(jax.grad(lambda p, b: loss_fn(p, b)[0]))
 
-        results = {{}}
-        for trainer in ["shard_map", "fsdp"]:
-            make = (make_train_step_fsdp if trainer == "fsdp"
-                    else make_train_step)
-            shard = (fsdp_state_shardings if trainer == "fsdp"
-                     else train_state_shardings)
-            st = init_train_state(params0, opt, mesh)
-            sh = shard(mesh, model.param_specs(), st)
-            st = jax.tree.map(lambda x, s: jax.device_put(x, s), st, sh)
-            step = make(loss_fn, opt, run.algo, mesh, agg_mode=spec.agg,
-                        grad_transform=moe.zero_inactive_expert_grads)
-            for i in range(steps):
-                batch = make_batch_shardings(mesh, data.batch(i))
-                st, m = step(st, batch, jax.random.fold_in(key, i))
-            results[trainer] = (jax.tree.map(np.asarray, st.params),
-                                jax.tree.map(np.asarray, st.h),
-                                float(m["loss"]))
-            # the expert-sparsity invariant holds in BOTH trainers: h only
-            # ever accumulates compressed MASKED grads, so inactive-expert
-            # slabs of h stay exactly zero.  Only checkable on the first
-            # step -- the router trains, so routing is no longer pinned to
-            # experts (0, 1) afterwards.
-            if steps == 1:
-                for name in ("wg", "wu", "wd"):
-                    hh = np.asarray(st.h["layers"]["moe"][name])
-                    assert np.all(hh[:, :, 2:] == 0.0), (trainer, name)
+        st = init_train_state(params0, opt, mesh)
+        sh = train_state_shardings(mesh, model.param_specs(), st)
+        st = jax.tree.map(lambda x, s: jax.device_put(x, s), st, sh)
+        step = make_train_step(loss_fn, opt, run.algo, mesh,
+                               agg_mode=spec.agg,
+                               grad_transform=moe.zero_inactive_expert_grads)
+        for i in range(steps):
+            batch = make_batch_shardings(mesh, data.batch(i))
+            st, m = step(st, batch, jax.random.fold_in(key, i))
+        # the expert-sparsity invariant: h only ever accumulates compressed
+        # MASKED grads, so inactive-expert slabs of h stay exactly zero.
+        # Only checkable on the first step -- the router trains, so routing
+        # is no longer pinned to experts (0, 1) afterwards.
+        if steps == 1:
+            for name in ("wg", "wu", "wd"):
+                hh = np.asarray(st.h["layers"]["moe"][name])
+                assert np.all(hh[:, :, 2:] == 0.0), name
 
         # the vmap oracle: per-worker grads on each worker's batch rows,
-        # masked exactly as the trainers' grad_transform masks them
+        # masked exactly as the trainer's grad_transform masks them
         w = jax.tree.map(jnp.asarray, params0)
         h = jax.tree.map(lambda p: jnp.zeros((n,) + p.shape), params0)
         h_avg = jax.tree.map(jnp.zeros_like, params0)
@@ -379,26 +363,18 @@ def _oracle_code(n_devices, mesh_shape, steps, fsdp_atol):
                 run.algo, wkeys, grads, h, h_avg, mode=spec.agg)
             w = jax.tree.map(lambda p, gg: p - lr * gg, w, g)
 
-        atols = {{"shard_map": 1e-6, "fsdp": {fsdp_atol}}}
-        for trainer, (p_t, h_t, loss_t) in results.items():
-            atol = atols[trainer]
-            for a, b in zip(jax.tree.leaves(p_t), jax.tree.leaves(w)):
-                np.testing.assert_allclose(a, np.asarray(b), rtol=1e-6,
-                                           atol=atol, err_msg=trainer)
-            if atol <= 1e-6:
-                for a, b in zip(jax.tree.leaves(h_t), jax.tree.leaves(h)):
-                    np.testing.assert_allclose(a, np.asarray(b), rtol=1e-6,
-                                               atol=1e-6, err_msg=trainer)
-        # both trainers ran the same forward at the same point: final-step
-        # loss metrics agree tightly even where the wires tie-flip
-        assert abs(results["shard_map"][2] - results["fsdp"][2]) < 1e-3, \\
-            (results["shard_map"][2], results["fsdp"][2])
+        for a, b in zip(jax.tree.leaves(st.params), jax.tree.leaves(w)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-6)
+        for a, b in zip(jax.tree.leaves(st.h), jax.tree.leaves(h)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-6)
         print("FIXED_ROUTING_ORACLE_MATCH")
     """
 
 
 def test_fixed_routing_step_matches_oracle_1dev():
-    """Single-worker tier-1 leg of the oracle pin: both trainers' fixed-
+    """Single-worker tier-1 leg of the oracle pin: the trainer's fixed-
     routing fine-tune step (expert-sparse leaf rules from the committed
     finetune_moe spec, grad_transform masking) tracks the vmap oracle."""
     import subprocess
@@ -409,7 +385,7 @@ def test_fixed_routing_step_matches_oracle_1dev():
 
     # run in-process-style but isolated: the module-level fixture already
     # holds jax state; a subprocess keeps the 1-device regime explicit
-    prog = textwrap.dedent(_oracle_code(1, (1, 1), 2, "1e-6"))
+    prog = textwrap.dedent(_oracle_code((1, 1), 2))
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_PLATFORMS"] = "cpu"
@@ -422,10 +398,8 @@ def test_fixed_routing_step_matches_oracle_1dev():
 @pytest.mark.slow
 def test_fixed_routing_step_matches_oracle_4dev():
     """4-worker leg: the shard_map trainer == the vmap oracle (tight) under
-    fixed routing with per-worker heterogeneous batches; the fsdp trainer
-    holds the structural expert-sparsity pins plus a loose parameter
-    tolerance (its vmap'd bf16 grads tie-flip block-top-k in embed)."""
-    out = run_with_devices(_oracle_code(4, (4, 1), 1, "2e-2") + "\n",
+    fixed routing with per-worker heterogeneous batches."""
+    out = run_with_devices(_oracle_code((4, 1), 1) + "\n",
                            n_devices=4)
     assert "FIXED_ROUTING_ORACLE_MATCH" in out
 
@@ -442,7 +416,7 @@ def test_committed_zoo_specs_pinned():
         spec = ExperimentSpec.from_json(raw)
         assert raw == spec.to_json(), fname
         assert spec.fingerprint() == fp, fname
-        assert spec.backend == "fsdp" and spec.mesh == "4x1", fname
+        assert spec.backend == "shard_map" and spec.mesh == "4x1", fname
         assert spec.compressor == "block_topk:256,16", fname
         assert spec.downlink == "qsgd:16", fname
 
@@ -485,7 +459,7 @@ def test_finetune_loop_stages_smoke():
     device: staged prerequisites, decorrelated eval stream, summary schema,
     exact wire accounting in the report."""
     spec = ExperimentSpec.from_json(
-        open(os.path.join(SPECS_DIR, "zoo_mamba2_fsdp.json")).read())
+        open(os.path.join(SPECS_DIR, "zoo_mamba2.json")).read())
     spec = dataclasses.replace(spec, mesh="1x1", n=1, steps=2)
     # seq_len 32: the mamba2 SSD scan runs in chunks of 32 tokens
     st = FinetuneSettings(global_batch=2, seq_len=32, eval_batches=1,

@@ -5,7 +5,7 @@ spec surface (parsing, fingerprint stability, backend gating), theory
 composition (staleness as a compressor perturbation), the reference oracle
 (depth-0 bitwise no-op, round-0 priming), the differential harness legs
 (depth-1 oracle == interpret over randomized bidirectional + federated
-trajectories), both trainers and the mid-pipeline checkpoint round-trip --
+trajectories), the trainer and the mid-pipeline checkpoint round-trip --
 plus the satellite regressions that rode along: `WireFormat.bits_per_round`
 int/float typing, `make_mesh` axis-name validation, the streaming pack
 kernel's bit-identity, and the fixed-order chunked decode.
@@ -392,7 +392,7 @@ def test_checkpoint_pipeline_fingerprint_gates_restore(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# 8. trainers (multi-device, subprocess)
+# 8. the trainer (multi-device, subprocess)
 # ---------------------------------------------------------------------------
 
 _TRAINER_PRELUDE = """
@@ -402,9 +402,8 @@ _TRAINER_PRELUDE = """
         from repro.core import EFBV, BlockTopK
         from repro.core.efbv import Pipeline
         from repro.optim import sgd, constant
-        from repro.train import (make_train_step, make_train_step_fsdp,
-                                 init_train_state, train_state_shardings,
-                                 fsdp_state_shardings)
+        from repro.train import (make_train_step, init_train_state,
+                                 train_state_shardings)
         from repro.launch.mesh import make_mesh
 
         mesh = make_mesh((4, 1))
@@ -430,15 +429,13 @@ _TRAINER_PRELUDE = """
         def fresh_params():
             return jax.tree.map(lambda a: jnp.array(a, copy=True), params0)
 
-        def run(trainer, agg, pipe, steps):
-            make = make_train_step if trainer == "shard_map" else make_train_step_fsdp
-            shard = (train_state_shardings if trainer == "shard_map"
-                     else fsdp_state_shardings)
+        def run(agg, pipe, steps):
             st = init_train_state(fresh_params(), opt, mesh, algo=algo,
                                   agg_mode=agg, pipeline=pipe)
-            sh = shard(mesh, specs, st)
+            sh = train_state_shardings(mesh, specs, st)
             st = jax.tree.map(lambda x, s: jax.device_put(x, s), st, sh)
-            step = make(loss_fn, opt, algo, mesh, agg_mode=agg, pipeline=pipe)
+            step = make_train_step(loss_fn, opt, algo, mesh, agg_mode=agg,
+                                   pipeline=pipe)
             for i in range(steps):
                 st, m = step(st, batch_at(i), jax.random.fold_in(key, i))
             return st
@@ -447,19 +444,18 @@ _TRAINER_PRELUDE = """
 
 @pytest.mark.slow
 def test_trainer_depth0_bit_identical_4dev():
-    """pipeline=depth:0 and pipeline=off are the SAME program on both
-    trainers and both wire modes -- the PR-5 trajectories cannot move."""
+    """pipeline=depth:0 and pipeline=off are the SAME program in both wire
+    modes -- the sequential trajectories cannot move."""
     out = run_with_devices(_TRAINER_PRELUDE + """
-        for trainer in ["shard_map", "fsdp"]:
-            for agg in ["dense_psum", "sparse_allgather"]:
-                a = run(trainer, agg, None, 4)
-                b = run(trainer, agg, Pipeline(depth=0), 4)
-                for la, lb in zip(jax.tree.leaves((a.params, a.h, a.h_avg)),
-                                  jax.tree.leaves((b.params, b.h, b.h_avg))):
-                    np.testing.assert_array_equal(np.asarray(la),
-                                                  np.asarray(lb))
-                assert a.inflight is None and b.inflight is None
-                print("IDENT", trainer, agg)
+        for agg in ["dense_psum", "sparse_allgather"]:
+            a = run(agg, None, 4)
+            b = run(agg, Pipeline(depth=0), 4)
+            for la, lb in zip(jax.tree.leaves((a.params, a.h, a.h_avg)),
+                              jax.tree.leaves((b.params, b.h, b.h_avg))):
+                np.testing.assert_array_equal(np.asarray(la),
+                                              np.asarray(lb))
+            assert a.inflight is None and b.inflight is None
+            print("IDENT", agg)
         print("DEPTH0_OK")
     """, n_devices=4)
     assert "DEPTH0_OK" in out
@@ -471,27 +467,26 @@ def test_trainer_depth1_semantics_4dev():
     h advances exactly as the sequential schedule's round 0; the in-flight
     buffer is carried; later rounds genuinely diverge from sequential."""
     out = run_with_devices(_TRAINER_PRELUDE + """
-        for trainer in ["shard_map", "fsdp"]:
-            for agg in ["dense_psum", "sparse_allgather"]:
-                pipe1 = run(trainer, agg, Pipeline(depth=1), 1)
-                seq1 = run(trainer, agg, None, 1)
-                for lp, l0 in zip(jax.tree.leaves(pipe1.params),
-                                  jax.tree.leaves(params0)):
-                    np.testing.assert_array_equal(np.asarray(lp),
-                                                  np.asarray(l0))
-                for la, lb in zip(jax.tree.leaves(pipe1.h),
-                                  jax.tree.leaves(seq1.h)):
-                    np.testing.assert_array_equal(np.asarray(la),
-                                                  np.asarray(lb))
-                assert pipe1.inflight is not None
-                pipe3 = run(trainer, agg, Pipeline(depth=1), 3)
-                seq3 = run(trainer, agg, None, 3)
-                diff = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(
-                    jax.tree.leaves(pipe3.params), jax.tree.leaves(seq3.params)))
-                assert diff > 0.0, (trainer, agg)
-                assert all(bool(jnp.all(jnp.isfinite(l)))
-                           for l in jax.tree.leaves(pipe3.params))
-                print("SEMANTICS", trainer, agg)
+        for agg in ["dense_psum", "sparse_allgather"]:
+            pipe1 = run(agg, Pipeline(depth=1), 1)
+            seq1 = run(agg, None, 1)
+            for lp, l0 in zip(jax.tree.leaves(pipe1.params),
+                              jax.tree.leaves(params0)):
+                np.testing.assert_array_equal(np.asarray(lp),
+                                              np.asarray(l0))
+            for la, lb in zip(jax.tree.leaves(pipe1.h),
+                              jax.tree.leaves(seq1.h)):
+                np.testing.assert_array_equal(np.asarray(la),
+                                              np.asarray(lb))
+            assert pipe1.inflight is not None
+            pipe3 = run(agg, Pipeline(depth=1), 3)
+            seq3 = run(agg, None, 3)
+            diff = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(
+                jax.tree.leaves(pipe3.params), jax.tree.leaves(seq3.params)))
+            assert diff > 0.0, agg
+            assert all(bool(jnp.all(jnp.isfinite(l)))
+                       for l in jax.tree.leaves(pipe3.params))
+            print("SEMANTICS", agg)
         print("DEPTH1_OK")
     """, n_devices=4)
     assert "DEPTH1_OK" in out

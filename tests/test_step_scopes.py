@@ -32,13 +32,13 @@ def scoped_instructions(hlo_text):
 
 
 @pytest.mark.parametrize("agg", ["dense_psum", "sparse_allgather"])
-@pytest.mark.parametrize("trainer", ["shard_map", "fsdp"])
-def test_compiled_step_names_its_layers(trainer, agg):
+@pytest.mark.parametrize("pipeline", ["off", "depth:1"])
+def test_compiled_step_names_its_layers(pipeline, agg):
     args = train.parse_args([
         "--arch", "qwen2-0.5b", "--smoke", "--mesh", "1x1",
         "--global-batch", "2", "--seq", "16", "--steps", "4",
         "--compressor", "block_topk:256,16", "--agg", agg,
-        "--trainer", trainer])
+        "--pipeline", pipeline])
     job = train.setup(args)
     batch = train.batch_at(job, args, 0)
     hlo = job.step_fn.lower(job.state, batch,

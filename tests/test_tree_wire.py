@@ -22,6 +22,8 @@ Plus the negative paths: every inconsistent-combo SpecError added since the
 spec PR asserted VERBATIM, and the new leaf_codecs rejections with them.
 """
 
+import json
+import os
 import random
 import re
 
@@ -32,7 +34,7 @@ import pytest
 
 import harness
 from _prop import given, settings, st
-from conftest import run_with_devices
+from conftest import REPO, run_with_devices
 from repro.core import ExperimentSpec, SpecError
 from repro.core.compressors import make_compressor
 from repro.core.spec import REFERENCE_PROBLEMS
@@ -357,9 +359,24 @@ def test_rejects_pipelined_reference_verbatim():
     with pytest.raises(SpecError, match=_verbatim(
             "the pipelined schedule double-buffers the trainer's wire "
             "payload; the reference backend runs the exact sequential "
-            "recursion (set pipeline='off', or backend='shard_map' / "
-            "'fsdp')")):
+            "recursion (set pipeline='off', or backend='shard_map')")):
         ExperimentSpec(n=2, d=8, pipeline="depth:1")
+
+
+@pytest.mark.parametrize("source", ["fields", "spec_file"])
+def test_rejects_fsdp_backend_verbatim(source):
+    # 'fsdp' names no trainer: a spec (or spec file) asking for it fails
+    # validation instead of falling back to the shard_map trainer
+    msg = "spec.backend = 'fsdp' not in ('reference', 'shard_map')"
+    with pytest.raises(SpecError, match=_verbatim(msg)):
+        if source == "fields":
+            ExperimentSpec(compressor="qsgd:16", backend="fsdp",
+                           problem="qwen2-0.5b", smoke=True, mesh="1x1",
+                           n=1, d=8)
+        else:
+            path = os.path.join(REPO, "examples", "specs", "zoo_qwen2.json")
+            raw = json.loads(open(path).read())
+            ExperimentSpec.from_json(json.dumps({**raw, "backend": "fsdp"}))
 
 
 def test_rejects_smoke_reference_problem_verbatim():

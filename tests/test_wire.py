@@ -504,17 +504,17 @@ def test_wire_trajectory_1_vs_8_devices():
     assert "WIRE_1V8_OK" in out
 
 
-@pytest.mark.parametrize("trainer", ["shard_map", "fsdp"])
-def test_bidirectional_compressed_downlink_tracks_model(trainer):
+@pytest.mark.parametrize("schedule", ["shard_map", "pipelined"])
+def test_bidirectional_compressed_downlink_tracks_model(schedule):
     """With a contractive downlink C_s, w tracks the model: the
     reconstruction error stays bounded and training still reduces the loss
-    -- in BOTH trainers (the FSDP path shares broadcast_global)."""
+    -- under the sequential AND the pipelined (depth:1) schedule."""
     from jax.sharding import PartitionSpec as P
     from repro.core import Downlink
+    from repro.core.efbv import Pipeline
     from repro.launch.mesh import make_mesh
     from repro.optim import constant, sgd
-    from repro.train import (fsdp_state_shardings, init_train_state,
-                             make_train_step, make_train_step_fsdp,
+    from repro.train import (init_train_state, make_train_step,
                              train_state_shardings)
 
     mesh = make_mesh((1, 1))
@@ -527,16 +527,15 @@ def test_bidirectional_compressed_downlink_tracks_model(trainer):
 
     algo = EFBV(BlockTopK(8, 4), lam=1.0, nu=1.0)
     opt = sgd(constant(0.1))
-    st = init_train_state(params, opt, mesh, bidirectional=True)
-    make_sh = (fsdp_state_shardings if trainer == "fsdp"
-               else train_state_shardings)
-    sh = make_sh(mesh, specs, st)
+    pipeline = Pipeline.parse("depth:1" if schedule == "pipelined" else "off")
+    st = init_train_state(params, opt, mesh, bidirectional=True, algo=algo,
+                          agg_mode="sparse_allgather", pipeline=pipeline)
+    sh = train_state_shardings(mesh, specs, st)
     st = jax.tree.map(lambda x, s: jax.device_put(x, s), st, sh)
-    make_step = (make_train_step_fsdp if trainer == "fsdp"
-                 else make_train_step)
-    step = make_step(loss_fn, opt, algo, mesh,
-                     agg_mode="sparse_allgather",
-                     downlink=Downlink(BlockTopK(8, 4)))
+    step = make_train_step(loss_fn, opt, algo, mesh,
+                           agg_mode="sparse_allgather",
+                           downlink=Downlink(BlockTopK(8, 4)),
+                           pipeline=pipeline)
     losses = []
     for i in range(30):
         kb_ = jax.random.fold_in(jax.random.key(7), i)
