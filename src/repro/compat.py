@@ -32,6 +32,18 @@ def pcast_varying(tree: PyTree, axes: Tuple[str, ...]) -> PyTree:
     return jax.lax.pcast(tree, tuple(axes), to="varying")
 
 
+def vma_of(*xs) -> frozenset:
+    """The mesh axes any of ``xs`` varies over."""
+    return frozenset().union(*(jax.typeof(x).vma for x in xs))
+
+
+def varying(x, vma: frozenset):
+    """``x`` marked varying over the axes of ``vma`` it does not vary over
+    yet."""
+    missing = tuple(sorted(vma - jax.typeof(x).vma))
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+
 def cost_analysis(compiled) -> dict:
     """compiled.cost_analysis(), or {} where the backend reports none."""
     return compiled.cost_analysis() or {}

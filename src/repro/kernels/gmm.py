@@ -56,15 +56,6 @@ def tiling(m: int, k: int, n: int):
     return tm, (k if k <= 2048 else 512), (n if n <= 2048 else 512)
 
 
-def _vma(*xs):
-    return frozenset().union(*(jax.typeof(x).vma for x in xs))
-
-
-def _varying(x, vma):
-    missing = tuple(sorted(vma - jax.typeof(x).vma))
-    return jax.lax.pcast(x, missing, to="varying") if missing else x
-
-
 def _expert_shards(groups: int) -> int:
     """Model shards the ``groups`` split over: the size of a GSPMD-auto
     ``model`` axis that divides them, else 1."""
@@ -87,7 +78,7 @@ def _mosaic(fn, vma, operands, in_specs=P(), out_specs=P()):
     if auto:
         fn = jax.shard_map(fn, in_specs=in_specs, out_specs=out_specs,
                            axis_names=set(auto), check_vma=False)
-    return _varying(fn(*operands), vma)
+    return compat.varying(fn(*operands), vma)
 
 
 def _oracle(lhs, rhs, group_sizes, *, transpose_rhs=False):
@@ -129,7 +120,8 @@ def _forward(impl, lhs, rhs, group_sizes, transpose_rhs=False):
         return jax.lax.psum(out, MODEL) if shards > 1 else out
 
     experts = P(MODEL) if shards > 1 else P()
-    return _mosaic(call, _vma(lhs, rhs, group_sizes), (lhs, rhs, group_sizes),
+    return _mosaic(call, compat.vma_of(lhs, rhs, group_sizes),
+                   (lhs, rhs, group_sizes),
                    in_specs=(P(), experts, P()))
 
 
@@ -147,7 +139,7 @@ def _weight_grad(impl, lhs, grad, group_sizes, groups, dtype):
             group_offset=_group_offset(local, shards),
             interpret=impl == "interpret")
 
-    return _mosaic(call, _vma(lhs, grad, group_sizes),
+    return _mosaic(call, compat.vma_of(lhs, grad, group_sizes),
                    (lhs.swapaxes(0, 1), grad, group_sizes),
                    out_specs=P(MODEL) if shards > 1 else P())
 
@@ -187,6 +179,7 @@ def gmm(lhs: Array, rhs: Array, group_sizes: Array,
     if group_sizes.shape != (rhs.shape[0] + 1,):
         raise ValueError(f"group_sizes {group_sizes.shape} must count "
                          f"{rhs.shape[0]} groups and the rows of none")
-    vma = _vma(lhs, rhs, group_sizes)
-    lhs, rhs, group_sizes = (_varying(x, vma) for x in (lhs, rhs, group_sizes))
+    vma = compat.vma_of(lhs, rhs, group_sizes)
+    lhs, rhs, group_sizes = (compat.varying(x, vma)
+                             for x in (lhs, rhs, group_sizes))
     return _gmm(impl, lhs, rhs.astype(lhs.dtype), group_sizes)

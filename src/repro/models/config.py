@@ -63,8 +63,10 @@ class ModelConfig:
     frontend: str = "none"
     vision_patches: int = 1024  # stub patch-embedding count for VLM inputs
 
-    # attention implementation: 'direct' (materialized S x S scores) or
-    # 'chunked' (online-softmax scan over KV chunks; §Perf iteration 3)
+    # attention implementation: 'direct' (exact softmax over every causal
+    # key in one pass: on the TPU through the Pallas splash kernel where the
+    # shapes allow, kernels/attention.py, else materialized S x S scores) or
+    # 'chunked' (the jnp online-softmax scan over KV chunks)
     attn_impl: str = "direct"
     # attention weight sharding when heads don't divide the model axis:
     # 'flat' (shard anyway; best for memory-bound) or 'replicate' (no score

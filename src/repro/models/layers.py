@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels import attention as KA
+
 Array = jax.Array
 
 
@@ -262,7 +264,9 @@ def attention(p, x: Array, *, n_heads: int, n_kv: int, hd: int,
     """Full-sequence attention (training / prefill).
 
     kv: optional externally-provided (k, v) for cross-attention.
-    impl: 'direct' (materialized scores) or 'chunked' (online softmax).
+    impl: 'direct' (exact softmax in one pass: causal self-attention through
+    the splash kernel where ``kernels/attention.py`` takes the shape, else
+    materialized scores) or 'chunked' (online softmax).
     scale: the scores' factor; None is 1 / sqrt(hd)."""
     B, S, _ = x.shape
     q, k, v = _project_qkv(p, x, n_heads, n_kv, hd)
@@ -279,6 +283,9 @@ def attention(p, x: Array, *, n_heads: int, n_kv: int, hd: int,
     if impl == "chunked" and causal and kv is None:
         out = _sdpa_chunked(q, k, v, window=window,
                             chunk=min(1024, k.shape[1]), scale=scale)
+    elif (impl == "direct" and causal and kv is None and not window
+          and KA.takes(S, n_heads, n_kv)):
+        out = KA.causal_attention(q, k, v, scale)
     else:
         mask = causal_mask(S, k.shape[1], window) if causal else None
         out = _sdpa(q, k, v, mask, scale)

@@ -137,6 +137,123 @@ def test_gqa_head_grouping():
     assert bool(jnp.all(jnp.isfinite(out)))
 
 
+def _qkv(B_, S_, H, K, hd, dtype=jnp.bfloat16):
+    ks = jax.random.split(jax.random.key(3), 4)
+    return (jax.random.normal(ks[0], (B_, S_, H, hd)).astype(dtype),
+            jax.random.normal(ks[1], (B_, S_, K, hd)).astype(dtype),
+            jax.random.normal(ks[2], (B_, S_, K, hd)).astype(dtype),
+            jax.random.normal(ks[3], (B_, S_, H, hd)).astype(dtype))
+
+
+@pytest.mark.parametrize("scale", [None, 1 / 64], ids=["sqrt-hd", "1/64"])
+def test_splash_attention_matches_sdpa(monkeypatch, scale):
+    """The splash kernel (interpret mode, blocks of 128) against the
+    materialised scores of ``_sdpa``: output and gradients w.r.t. q, k and
+    v, on two rows of 256 tokens, 6 query heads over 2 kv heads of 64."""
+    from repro.kernels import attention as KA
+    monkeypatch.setattr(KA, "BLOCK", 128)
+    assert KA.block(256) == 128
+    q, k, v, ct = _qkv(2, 256, 6, 2, 64)
+
+    def run(impl):
+        f = lambda *a: jnp.sum((KA.causal_attention(*a, scale, impl) * ct)
+                               .astype(jnp.float32))
+        return (KA.causal_attention(q, k, v, scale, impl),
+                *jax.grad(f, (0, 1, 2))(q, k, v))
+
+    for got, want in zip(run("interpret"), run("oracle")):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        np.testing.assert_allclose(got, want, rtol=2e-2,
+                                   atol=1e-2 * np.abs(want).max())
+
+
+def test_attention_dispatch_takes_the_kernel_only_where_it_can(monkeypatch):
+    """``attention()`` runs the splash kernel for causal self-attention
+    without a window at S a multiple of 128 from ``MIN_SEQ`` on, on the
+    TPU, and materialised scores off the TPU, for a window, for given keys
+    and values, for non-causal attention, below ``MIN_SEQ``, at S not a
+    multiple of 128, and for ``impl='chunked'``."""
+    from repro.kernels import attention as KA
+    d, H, K, hd = 128, 2, 1, 64
+    p, _ = L.attention_init(jax.random.key(1), d, H, K, hd, qkv_bias=False)
+    calls = []
+    kernel = KA.causal_attention
+    monkeypatch.setattr(KA, "causal_attention",
+                        lambda *a, **kw: calls.append(a) or kernel(*a, **kw))
+    assert KA.MIN_SEQ % 128 == 0
+
+    def runs_kernel(S_=KA.MIN_SEQ, **kw):
+        calls.clear()
+        x = jax.random.normal(KEY, (1, S_, d)).astype(jnp.bfloat16)
+        pos = jnp.broadcast_to(jnp.arange(S_), (1, S_))
+        out = L.attention(p, x, n_heads=H, n_kv=K, hd=hd, positions=pos,
+                          theta=1e4, **kw)
+        assert out.shape == (1, S_, d)
+        return bool(calls)
+
+    kv = _qkv(1, KA.MIN_SEQ, K, K, hd)[1:3]
+    assert not runs_kernel()                                # off the TPU
+    monkeypatch.setattr(KA, "default_impl", lambda: "interpret")
+    assert runs_kernel()                                    # as on the TPU
+    assert not runs_kernel(window=32)
+    assert not runs_kernel(kv=kv)
+    assert not runs_kernel(causal=False)
+    assert not runs_kernel(S_=KA.MIN_SEQ - 128)
+    assert not runs_kernel(S_=KA.MIN_SEQ + 64)
+    assert not runs_kernel(impl="chunked")
+
+
+def test_splash_attention_under_the_trainers_shard_map():
+    """Inside a shard_map manual over 'data' with 'model' GSPMD-auto, as the
+    trainer calls the model: on a 2 x 2 mesh each 'model' shard runs the
+    kernel (interpret mode) on its own heads and matches the materialised
+    scores, loss and gradients; on a 1 x 4 mesh 'model' does not divide the
+    2 kv heads, and the materialised path runs."""
+    from conftest import run_with_devices
+    out = run_with_devices("""
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import PartitionSpec as P
+        from repro import compat
+        from repro.kernels import attention as KA
+        from repro.models import layers as L
+
+        B, S, d, H, K, hd = 4, 128, 128, 4, 2, 32
+        KA.MIN_SEQ = S   # the dispatch's threshold, lowered to a short row
+        p, _ = L.attention_init(jax.random.key(0), d, H, K, hd, False)
+        x = jax.random.normal(jax.random.key(1), (B, S, d)).astype(jnp.bfloat16)
+        pos = jnp.broadcast_to(jnp.arange(S), (B, S))
+
+        def worker(p, x):
+            loss = lambda p: jnp.sum(jnp.square(L.attention(
+                p, x, n_heads=H, n_kv=K, hd=hd, positions=pos[:x.shape[0]],
+                theta=1e4).astype(jnp.float32)))
+            l, g = jax.value_and_grad(loss)(compat.pcast_varying(p, ("data",)))
+            return l[None], jax.tree.map(lambda a: a[None], g)
+
+        for shape, kernel in (((2, 2), True), ((1, 4), False)):
+            mesh = compat.make_mesh(shape, ("data", "model"))
+            res = {}
+            for impl in ("interpret", "oracle"):
+                KA.default_impl = lambda impl=impl: impl
+                step = jax.jit(compat.shard_map(
+                    lambda p, x: worker(p, x), mesh=mesh,
+                    in_specs=(P(), P("data")), out_specs=P("data"),
+                    manual_axes=("data",)))
+                with jax.set_mesh(mesh):
+                    jaxpr = str(jax.make_jaxpr(step)(p, x))
+                    res[impl] = step(p, x)
+                assert ("pallas_call" in jaxpr) == (impl == "interpret"
+                                                     and kernel), (shape, impl)
+            for a, b in zip(jax.tree.leaves(res["interpret"]),
+                            jax.tree.leaves(res["oracle"])):
+                a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+                np.testing.assert_allclose(a, b, rtol=2e-2,
+                                           atol=1e-2 * np.abs(b).max())
+        print("OK")
+    """, 4)
+    assert "OK" in out
+
+
 def test_rope_is_relative():
     """RoPE: q.k depends only on relative offsets."""
     hd = 16

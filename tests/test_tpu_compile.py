@@ -14,7 +14,9 @@ widths (d 1536, experts of 512, 8 held) on one 4,096-token row's 32,768
 assignments, the decode-sum at its stacked expert leaf of
 8 x 8 x 1536 x 512, and the grouped products at dbrx-132b's (d 6144, 16
 experts of 10,752, top-4) on the described host's four chips as a 4-way
-model axis, where the experts are expert-parallel.
+model axis, where the experts are expert-parallel.  The splash attention
+kernels compile at granite's attention shapes (24 query heads and 8 kv
+heads of 64 over 4,096 tokens).
 """
 
 from __future__ import annotations
@@ -196,6 +198,31 @@ def test_grouped_products_expert_parallel_compile(v5e_host):
                 and any(w in line.split("=")[1] for w in whole)]
     assert not gathered, gathered
     assert any(w in text for w in local)
+
+
+def test_splash_attention_compiles_at_granite_shapes(one_chip):
+    """Causal attention at granite-moe-3b-a800m's shapes (1 x 4,096 tokens,
+    24 query heads and 8 kv heads of 64, scores times 1/64) under
+    ``jax.grad``: the splash forward, dq and dkv kernels, and temporaries
+    below one f32 score tensor (24 x 4,096^2 x 4 B = 1.61e9 B), which the
+    materialised path writes."""
+    from repro.kernels import attention
+
+    q = jax.ShapeDtypeStruct((1, 4096, 24, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4096, 8, 64), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(attention.causal_attention(
+            *a, scale=1 / 64, impl="kernel").astype(jnp.float32)),
+            (0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(grads).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    for phase in ("fwd", "dq", "dkv"):
+        assert re.search(rf"%splash_mha_{phase}_\w+(\.\d+)? = ", text), phase
+    assert compiled.memory_analysis().temp_size_in_bytes < 24 * 4096**2 * 4
 
 
 @pytest.mark.parametrize("downlink", ["", "block_topk:256,16"],
